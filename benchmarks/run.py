@@ -115,6 +115,8 @@ def main() -> None:
         list_solvers()
         return
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig1_convergence, table2_timing, fig2a_speedup,
                             fig2b_partition, recovery_bench, roofline_report,
                             bench_lazy_inner, bench_partition, bench_ingest,

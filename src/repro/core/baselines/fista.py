@@ -47,18 +47,20 @@ def fista_history(obj, reg: Regularizer, X: Array, y: Array, w0: Array,
     """
     L = obj.lipschitz(X) + reg.lam1
 
-    def smooth_loss(w):
+    def smooth_loss(w, X, y):
         return obj.loss(w, X, y) + 0.5 * reg.lam1 * jnp.sum(w * w)
 
     reg_l1 = Regularizer(0.0, reg.lam2)   # L2 handled smoothly above
     eta = 1.0 / L
+    # X and y are arguments, not closed-over constants: a jitted closure
+    # embeds the whole design matrix in the program as a literal
     grad = jax.jit(jax.grad(smooth_loss))
-    obj_val = jax.jit(lambda w: obj.loss(w, X, y) + reg.value(w))
+    obj_val = jax.jit(lambda w, X, y: obj.loss(w, X, y) + reg.value(w))
 
     hist: List[float] = []
 
     def emit(w):
-        v = float(obj_val(w))
+        v = float(obj_val(w, X, y))
         hist.append(v)
         if on_record is not None:
             on_record(w, v)
@@ -66,7 +68,7 @@ def fista_history(obj, reg: Regularizer, X: Array, y: Array, w0: Array,
     w, v, t = w0, w0, 1.0
     emit(w)
     for i in range(iters):
-        g = grad(v)
+        g = grad(v, X, y)
         w_next = reg_l1.prox(v - eta * g, eta)
         t_next = 0.5 * (1.0 + (1.0 + 4.0 * t * t) ** 0.5)
         v = w_next + ((t - 1.0) / t_next) * (w_next - w)

@@ -62,7 +62,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import plan as plan_mod
 from repro.obs import roofline as obs_roofline
 from repro.core import svrg
@@ -850,9 +849,9 @@ def _distributed_statics(cfg: PScopeConfig, mesh, axis: str,
     out_specs = plan_mod.ShardStatics(
         xdup=P(axis), rep_row=P(axis),
         member=P(axis) if with_member else None)
-    sharded = compat.shard_map(build, mesh=mesh,
-                               in_specs=(P(axis), P(axis)),
-                               out_specs=out_specs, check_vma=False)
+    sharded = jax.shard_map(build, mesh=mesh,
+                            in_specs=(P(axis), P(axis)),
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(sharded)(csr.vals, csr.cols)
 
 
@@ -940,7 +939,7 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
         if with_statics:
             fn = lambda w, key, vals, y, cols, st: body(w, key, vals, y,
                                                         cols, statics=st)
-        return compat.shard_map(
+        return jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
             # the inner scan carry starts replicated (u0 = w_t) and becomes
             # device-varying through per-shard sampling; disable the VMA
@@ -1130,8 +1129,8 @@ def make_stacked_outer_step_core(obj: Objective, reg: Regularizer,
             def fn(w, key, vals, cols, y, slots, st):
                 st = jax.tree_util.tree_map(lambda x: x[0], st)
                 return body(w, key, vals, cols, y, slots, statics=st)
-        return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=P(), check_vma=False)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)
 
     def outer_step(state: PScopeState, vals, cols, y, slots,
                    statics=None) -> PScopeState:
@@ -1163,9 +1162,9 @@ def _stacked_statics(cfg: PScopeConfig, mesh, axis: str, vals_g, cols_g,
     out_specs = plan_mod.ShardStatics(
         xdup=P(axis), rep_row=P(axis),
         member=P(axis) if with_member else None)
-    sharded = compat.shard_map(build_block, mesh=mesh,
-                               in_specs=(P(axis), P(axis)),
-                               out_specs=out_specs, check_vma=False)
+    sharded = jax.shard_map(build_block, mesh=mesh,
+                            in_specs=(P(axis), P(axis)),
+                            out_specs=out_specs, check_vma=False)
     return jax.jit(sharded)(vals_g, cols_g)
 
 
@@ -1187,9 +1186,9 @@ def _stacked_objective_value(obj: Objective, reg: Regularizer, mesh,
         valid = (slots >= 0).astype(rows.dtype)
         return jax.lax.psum(jnp.sum(rows * valid[:, None]), axis)
 
-    sharded = compat.shard_map(local_loss_sum, mesh=mesh,
-                               in_specs=(P(),) + (P(axis),) * 4,
-                               out_specs=P(), check_vma=False)
+    sharded = jax.shard_map(local_loss_sum, mesh=mesh,
+                            in_specs=(P(),) + (P(axis),) * 4,
+                            out_specs=P(), check_vma=False)
 
     def value(w, vals, cols, y, slots):
         return sharded(w, vals, cols, y, slots) / (p_total * n_k) \

@@ -48,24 +48,27 @@ def _rho_pow(r, lam1_eta):
     return jnp.exp(r * jnp.log1p(-lam1_eta))
 
 
-def _beta(r, lam1_eta):
+def _beta(r, lam1_eta, expm1=jnp.expm1):
     """beta_r = sum_{i=1..r} rho^{i-1} = (1 - rho^r)/lam1_eta; = r at 0.
 
     Uses expm1/log1p to avoid the (1-rho^r)/(1-rho) cancellation that
-    loses ~3 digits in float32 when lam1*eta ~ 1e-6.
+    loses ~3 digits in float32 when lam1*eta ~ 1e-6.  `expm1` is
+    swappable because the TPU kernel lowering has none (see
+    kernels/lazy_prox.expm1_poly); this oracle keeps the exact one.
     """
     r = jnp.asarray(r, jnp.float32)
-    geom = -jnp.expm1(r * jnp.log1p(-lam1_eta)) / jnp.maximum(lam1_eta, 1e-38)
+    geom = -expm1(r * jnp.log1p(-lam1_eta)) / jnp.maximum(lam1_eta, 1e-38)
     # below ~1e-30 the f32 log1p underflows and the geometric form is
     # 0/0: treat as the lam1 = 0 linear regime (they agree to <1e-28)
     return jnp.where(lam1_eta > 1e-30, geom, r)
 
 
-def _affine_phase(u0, s, r, z, eta, lam1, lam2):
+def _affine_phase(u0, s, r, z, eta, lam1, lam2, expm1=jnp.expm1):
     """u after r steps of (*) assuming the sign stays s the whole time."""
     lam1_eta = lam1 * eta
     c = z + s * lam2
-    return _rho_pow(r, lam1_eta) * u0 - eta * c * _beta(r, lam1_eta)
+    return (_rho_pow(r, lam1_eta) * u0
+            - eta * c * _beta(r, lam1_eta, expm1))
 
 
 def _exact_step(u, z, eta, lam1, lam2):
@@ -94,8 +97,10 @@ def _q0_branch_steps(u0, s, z, eta, lam1, lam2, q_max, affine=None):
     # rho == 1: alpha_q = q  =>  q0 = floor(su0 / (eta*c))
     lin_form = su0 / (eta * safe_c)
     q0f = jnp.where(lam1_eta > 1e-30, log_form, lin_form)
-    q0 = jnp.floor(jnp.where(c_hat > 0, q0f, big)).astype(jnp.int32)
-    q0 = jnp.clip(q0, 0, q_max)
+    # clip in float before the int cast: lin_form reaches ~1e30 for a
+    # tiny c_hat, and an out-of-range float -> int32 cast is undefined
+    q0 = jnp.floor(jnp.clip(jnp.where(c_hat > 0, q0f, big), 0.0, big))
+    q0 = q0.astype(jnp.int32)
 
     if affine is None:
         def affine(u0_, s_, r, z_):

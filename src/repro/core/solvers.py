@@ -548,7 +548,8 @@ def _run_pscope_mesh(obj, reg, part, cfg, trace):
                                 pcfg, spec)
     trace.meta["comm_units"] = "bytes"
     trace.meta["mesh"] = {"num_processes": res.num_processes,
-                          "local_worker_ids": list(res.worker_ids)}
+                          "local_worker_ids": list(res.worker_ids),
+                          "devices": list(res.worker_devices)}
     trace.record_history(res.values, res.nnz,
                          comm_per_record=res.comm_bytes_per_round,
                          total_seconds=res.seconds)

@@ -45,7 +45,7 @@ def fused_prox_svrg_pallas(u: jax.Array, g_u: jax.Array, g_w: jax.Array,
     rows, lanes = u.shape
     assert lanes == _LANES and rows % 8 == 0, (rows, lanes)
     block_rows = min(_BLOCK_ROWS, rows)
-    grid = (rows // block_rows,)
+    grid = (pl.cdiv(rows, block_rows),)
     bspec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     kernel = functools.partial(_fused_kernel, eta=eta, lam1=lam1, lam2=lam2)
     return pl.pallas_call(
@@ -55,6 +55,7 @@ def fused_prox_svrg_pallas(u: jax.Array, g_u: jax.Array, g_w: jax.Array,
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         interpret=interpret,
+        name="fused_prox_svrg",
     )(u, g_u, g_w, z)
 
 
@@ -72,7 +73,7 @@ def fused_prox_svrg_diff_pallas(u: jax.Array, dv: jax.Array, z: jax.Array,
     rows, lanes = u.shape
     assert lanes == _LANES and rows % 8 == 0, (rows, lanes)
     block_rows = min(_BLOCK_ROWS, rows)
-    grid = (rows // block_rows,)
+    grid = (pl.cdiv(rows, block_rows),)
     bspec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     kernel = functools.partial(_fused_diff_kernel, eta=eta, lam1=lam1,
                                lam2=lam2)
@@ -83,4 +84,5 @@ def fused_prox_svrg_diff_pallas(u: jax.Array, dv: jax.Array, z: jax.Array,
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         interpret=interpret,
+        name="fused_prox_svrg_diff",
     )(u, dv, z)

@@ -30,35 +30,34 @@ def _lazy_prox_kernel(u_ref, z_ref, q_ref, o_ref, *, eta, lam1, lam2, q_max):
     o_ref[...] = _catch_up_block(u, z, q, eta, lam1, lam2, q_max)
 
 
+def expm1_poly(x):
+    """expm1 from ops the TPU kernel lowering accepts (it has no expm1).
+
+    Below |x| = 0.5 a degree-9 Taylor polynomial (truncation error
+    < 1.1e-8 |x|), above it exp(x) - 1, whose cancellation error is
+    at most one ulp of 1 over |x| >= 0.5.  Either way the relative
+    error stays within a few f32 ulps, the accuracy `_beta`'s
+    geometric form needs when lam1*eta is tiny.
+    """
+    poly = jnp.zeros_like(x)
+    for n in range(9, 0, -1):
+        poly = (poly + 1.0) * x / n
+    return jnp.where(jnp.abs(x) < 0.5, poly, jnp.exp(x) - 1.0)
+
+
 def _catch_up_block(u, z, q, eta, lam1, lam2, q_max):
-    """Branch-free Lemma-11 catch-up on one VMEM tile (same math as
-    core.recovery.recovery_catch_up, inlined so Pallas traces only
-    elementwise VPU ops)."""
+    """Branch-free Lemma-11 catch-up on one VMEM tile: the phase
+    structure of core.recovery.recovery_catch_up, with `expm1_poly` in
+    the affine phases so Pallas traces only ops Mosaic lowers."""
+    def affine(u0, s, r, z_):
+        return _rec._affine_phase(u0, s, r, z_, eta, lam1, lam2,
+                                  expm1=expm1_poly)
+
     s0 = jnp.sign(u)
     q0 = _rec._q0_branch_steps(u, jnp.where(s0 == 0, 1.0, s0), z, eta, lam1,
-                               lam2, q_max)
+                               lam2, q_max, affine=affine)
     q0 = jnp.where(s0 == 0, 0, q0)
-    a = jnp.minimum(q, q0)
-    u_a = jnp.where(s0 == 0, u, _rec._affine_phase(u, s0, a, z, eta, lam1,
-                                                   lam2))
-    done = q <= a
-
-    u_b = _rec._exact_step(u_a, z, eta, lam1, lam2)
-    u_res = jnp.where(done, u_a, u_b)
-    done_b = done | (q <= a + 1)
-
-    absorbed = (u_b == 0.0) & (jnp.abs(z) <= lam2)
-    done_zero = done_b | absorbed
-
-    u_c = _rec._exact_step(u_b, z, eta, lam1, lam2)
-    jumped = u_b != 0.0
-    s1 = jnp.where(jumped, jnp.sign(u_b), jnp.sign(u_c))
-    start = jnp.where(jumped, u_b, u_c)
-    r = jnp.maximum(jnp.where(jumped, q - a - 1, q - a - 2), 0)
-    u_phase_b = _rec._affine_phase(start, s1, r, z, eta, lam1, lam2)
-
-    out = jnp.where(done_zero, jnp.where(done_b, u_res, 0.0), u_phase_b)
-    return jnp.where(q == 0, u, out)
+    return _rec._finish_catch_up(u, z, q, eta, lam1, lam2, q0, affine)
 
 
 @functools.partial(jax.jit,
@@ -70,7 +69,7 @@ def lazy_prox_pallas(u: jax.Array, z: jax.Array, q: jax.Array, *, eta: float,
     rows, lanes = u.shape
     assert lanes == _LANES and rows % 8 == 0, (rows, lanes)
     block_rows = min(_BLOCK_ROWS, rows)
-    grid = (rows // block_rows,)
+    grid = (pl.cdiv(rows, block_rows),)
     bspec = pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))
     kernel = functools.partial(_lazy_prox_kernel, eta=eta, lam1=lam1,
                                lam2=lam2, q_max=1 << 30)
@@ -81,4 +80,5 @@ def lazy_prox_pallas(u: jax.Array, z: jax.Array, q: jax.Array, *, eta: float,
         out_specs=bspec,
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         interpret=interpret,
+        name="lazy_prox",
     )(u, z, q)

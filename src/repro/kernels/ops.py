@@ -1,10 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles shape canonicalization (padding to (rows, 128) tiles), dtype
-promotion, and the interpret-mode switch: on the CPU container kernels
-execute via `interpret=True`; on a real TPU backend they compile to
-Mosaic.  `USE_PALLAS=0` env var falls back to the jnp reference (used to
-A/B the kernels inside the full system).
+promotion, and the interpret-mode switch `_interpret()`, keyed on the
+backend: off the TPU kernels run in Pallas interpret mode; on a TPU they
+compile to Mosaic and never run interpreted.  `USE_PALLAS=0` env var
+falls back to the jnp reference (used to A/B the kernels inside the
+full system).
 """
 from __future__ import annotations
 
@@ -103,14 +104,13 @@ def fused_prox_svrg_diff(u: jax.Array, dv: jax.Array, z: jax.Array, *,
 
 
 def _tiles_with_spare(x: jax.Array, d: int, dtype) -> jax.Array:
-    """(rows, 128) tiles holding x's first d entries with >= 1 spare tail
+    """(T, 8, 128) tiles holding x's first d entries with >= 1 spare tail
     slot — the dummy coordinate padded plan rows point at."""
-    rows = max(8, -(-(d + 1) // _LANES))
-    rows = -(-rows // 8) * 8
+    tiles = -(-(d + 1) // (8 * _LANES))
     flat = x.reshape(-1).astype(dtype)
-    pad = rows * _LANES - d
+    pad = tiles * 8 * _LANES - d
     return jnp.concatenate([flat, jnp.zeros((pad,), dtype)]).reshape(
-        rows, _LANES)
+        tiles, 8, _LANES)
 
 
 def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
@@ -137,43 +137,41 @@ def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
     b = inner_batch
     k = S // b
     kp = -(-k // _LANES) * _LANES
-    Sp = b * kp
     padw = kp - k
 
     def pad_slots(a, fill, dtype):
         a3 = a.reshape(M, b, k).astype(dtype)
         return jnp.pad(a3, ((0, 0), (0, 0), (0, padw)),
-                       constant_values=fill).reshape(M, Sp)
+                       constant_values=fill)
 
     # dummy column d = the guaranteed spare tile slot (value 0, z 0,
     # staleness 0: its update is the identity on a zero coordinate)
-    cflat_p = pad_slots(plan.cflat, d, jnp.int32)
+    cflat_p = pad_slots(plan.cflat, d, jnp.int32).reshape(M, 1, -1)
     q_p = pad_slots(plan.q, 0, jnp.int32)
     # remap duplicate representatives from slot space S to padded slot
-    # space Sp; padding slots represent themselves
+    # space b * kp; padding slots represent themselves
     rep3 = plan.rep.reshape(M, b, k)
     rep_padded = jnp.pad(rep3 // k * kp + rep3 % k,
                          ((0, 0), (0, 0), (0, padw)))
     slot_iota = (jax.lax.broadcasted_iota(jnp.int32, (M, b, kp), 2)
                  + jax.lax.broadcasted_iota(jnp.int32, (M, b, kp), 1) * kp)
     pad_mask = jax.lax.broadcasted_iota(jnp.int32, (M, b, kp), 2) >= k
-    rep_p = jnp.where(pad_mask, slot_iota, rep_padded).reshape(M, Sp)
+    rep_p = jnp.where(pad_mask, slot_iota, rep_padded).reshape(M, 1, -1)
     # encoded shards deliver vb as uint16 bf16 bits (plan.EpochGathers);
-    # pad in the native dtype and let the kernel bitcast in VMEM —
+    # pad in the native dtype and let the kernel decode in VMEM —
     # padding bits 0x0000 decode to exactly 0.0f, same as f32 padding
-    vals_bf16 = gathers.vb.dtype == jnp.uint16
-    vb_p = pad_slots(gathers.vb.reshape(M, S), 0,
-                     jnp.uint16 if vals_bf16 else jnp.float32)
+    vals_dtype = (jnp.uint16 if gathers.vb.dtype == jnp.uint16
+                  else jnp.float32)
+    vb_p = pad_slots(gathers.vb.reshape(M, S), 0, vals_dtype)
     zg_p = pad_slots(gathers.zg, 0.0, jnp.float32)
-    u0_t = _tiles_with_spare(u0, d, jnp.float32)
-    z_t = _tiles_with_spare(z, d, jnp.float32)
-    qf_t = _tiles_with_spare(plan.qf, d, jnp.int32)
     out = fused_lazy_epoch_pallas(
-        u0_t, z_t, qf_t, cflat_p, q_p, rep_p, vb_p,
-        gathers.yb.reshape(M, b).astype(jnp.float32), zg_p,
-        gathers.sw.reshape(M, b).astype(jnp.float32), h_prime=h_prime,
-        eta=eta, eta_eff=eta_eff, lam1=lam1, lam2=lam2, b=b,
-        vals_bf16=vals_bf16, interpret=_interpret())
+        _tiles_with_spare(u0, d, jnp.float32),
+        _tiles_with_spare(z, d, jnp.float32),
+        _tiles_with_spare(plan.qf, d, jnp.int32), cflat_p, q_p, rep_p, vb_p,
+        gathers.yb.reshape(M, b, 1).astype(jnp.float32), zg_p,
+        gathers.sw.reshape(M, b, 1).astype(jnp.float32), h_prime=h_prime,
+        eta=eta, eta_eff=eta_eff, lam1=lam1, lam2=lam2,
+        interpret=_interpret())
     return out.reshape(-1)[:d].astype(u0.dtype)
 
 

@@ -340,8 +340,7 @@ def newest_fence(plane: ControlPlane, ns: str) -> tuple[int, Optional[int]]:
 # ---------------------------------------------------------------------------
 
 def run_service_host(bind_address: str, num_processes: int, *,
-                     heartbeat_interval_s: int = 10,
-                     max_missing_heartbeats: int = 8640,
+                     heartbeat_timeout_s: int = 86400,
                      ready_event: Optional[threading.Event] = None,
                      stop_event: Optional[threading.Event] = None) -> None:
     """Host the `jax.distributed` coordination service in THIS process,
@@ -349,7 +348,7 @@ def run_service_host(bind_address: str, num_processes: int, *,
     close the service socket, so survivor KV traffic and gloo
     communicator rendezvous keep working through any single failure.
 
-    `max_missing_heartbeats` defaults high for the same reason as
+    `heartbeat_timeout_s` defaults high for the same reason as
     `init_distributed(elastic=True)`: the service must not declare a
     silently-dead task failed (and push a fatal error to every polling
     client) while the elastic layer is busy recovering from it.
@@ -358,16 +357,14 @@ def run_service_host(bind_address: str, num_processes: int, *,
     the service is listening — callers forking this as a child can wait
     on the ``SERVICE-HOST UP`` stdout line instead.
     """
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax
 
     if ":" not in bind_address:
         raise ValueError(f"bind address must be host:port, got "
                          f"{bind_address!r}")
     bind = "[::]:" + bind_address.rsplit(":", 1)[1]
-    service = xe.get_distributed_runtime_service(
-        bind, num_processes,
-        heartbeat_interval=heartbeat_interval_s,
-        max_missing_heartbeats=max_missing_heartbeats)
+    service = _jax.get_distributed_runtime_service(
+        bind, num_processes, heartbeat_timeout=int(heartbeat_timeout_s))
     print(f"SERVICE-HOST UP {bind_address} ({num_processes} ranks)",
           flush=True)
     if ready_event is not None:

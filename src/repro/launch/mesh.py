@@ -173,7 +173,7 @@ def init_distributed(coordinator: Optional[str] = None,
                      retries: Optional[int] = None,
                      backoff_s: float = 1.0,
                      elastic: bool = False,
-                     service_max_missing_heartbeats: int = 8640,
+                     heartbeat_timeout_s: int = 86400,
                      external_service: Optional[bool] = None
                      ) -> Dict[str, int]:
     """Bring this process into the `jax.distributed` job (idempotent).
@@ -197,11 +197,11 @@ def init_distributed(coordinator: Optional[str] = None,
     `elastic=True` routes through the lower-level distributed-state
     initializer so the coordination-service liveness knobs can be
     raised: by default the service declares a silent task dead after
-    ~100 s (10 s x 10 heartbeats) and then TERMINATES every other
-    process — exactly what an elastic run must prevent, because
-    `launch.elastic` does its own KV-store heartbeat detection and
-    keeps the survivors alive.  `service_max_missing_heartbeats`
-    (default 8640 == one silent day) is the override.
+    100 s and then TERMINATES every other process — exactly what an
+    elastic run must prevent, because `launch.elastic` does its own
+    KV-store heartbeat detection and keeps the survivors alive.
+    `heartbeat_timeout_s` (default 86400 == one silent day) is the
+    override.
 
     `external_service=True` (env ``REPRO_SERVICE_EXTERNAL=1``) declares
     that the coordination service is hosted OUTSIDE the mesh ranks (a
@@ -242,15 +242,13 @@ def init_distributed(coordinator: Optional[str] = None,
             if external_service:
                 _init_client_only(coordinator, num_processes, process_id,
                                   timeout=initialization_timeout,
-                                  service_max_missing_heartbeats=
-                                  service_max_missing_heartbeats)
+                                  heartbeat_timeout_s=heartbeat_timeout_s)
             else:
                 _init_with_retries(coordinator, num_processes, process_id,
                                    timeout=initialization_timeout,
                                    retries=max(1, retries),
                                    backoff_s=backoff_s, elastic=elastic,
-                                   service_max_missing_heartbeats=
-                                   service_max_missing_heartbeats)
+                                   heartbeat_timeout_s=heartbeat_timeout_s)
         elif num_processes is not None and num_processes > 1:
             raise ValueError("multi-process init needs a coordinator "
                              "address (host:port)")
@@ -263,8 +261,7 @@ def init_distributed(coordinator: Optional[str] = None,
 
 
 def _init_client_only(coordinator: str, num_processes, process_id, *,
-                      timeout: float,
-                      service_max_missing_heartbeats: int) -> None:
+                      timeout: float, heartbeat_timeout_s: int) -> None:
     """Join an EXTERNALLY-hosted coordination service: build only the
     distributed-runtime client and hand it to `global_state`, so this
     process — rank 0 included — is a peer like any other and its death
@@ -276,7 +273,7 @@ def _init_client_only(coordinator: str, num_processes, process_id, *,
     elastic layer replaces with its own chunk-boundary verdicts.
     """
     from jax._src import distributed as _dist
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax
 
     if num_processes is None or process_id is None:
         raise ValueError("external-service init needs explicit "
@@ -285,11 +282,10 @@ def _init_client_only(coordinator: str, num_processes, process_id, *,
     gs.coordinator_address = coordinator
     gs.num_processes = int(num_processes)
     gs.process_id = int(process_id)
-    gs.client = xe.get_distributed_runtime_client(
+    gs.client = _jax.get_distributed_runtime_client(
         coordinator, int(process_id),
         init_timeout=int(timeout),
-        heartbeat_interval=2,
-        max_missing_heartbeats=service_max_missing_heartbeats,
+        heartbeat_timeout=int(heartbeat_timeout_s),
         use_compression=True)
     try:
         gs.client.connect()
@@ -303,8 +299,7 @@ def _init_client_only(coordinator: str, num_processes, process_id, *,
 
 def _init_with_retries(coordinator: str, num_processes, process_id, *,
                        timeout: float, retries: int, backoff_s: float,
-                       elastic: bool,
-                       service_max_missing_heartbeats: int) -> None:
+                       elastic: bool, heartbeat_timeout_s: int) -> None:
     """Bounded-retry `jax.distributed` bring-up (see `init_distributed`)."""
     from jax._src import distributed as _dist
     last: Optional[BaseException] = None
@@ -315,14 +310,17 @@ def _init_with_retries(coordinator: str, num_processes, process_id, *,
                     coordinator_address=coordinator,
                     num_processes=num_processes, process_id=process_id,
                     initialization_timeout=int(timeout),
-                    service_max_missing_heartbeats=
-                    service_max_missing_heartbeats)
+                    heartbeat_timeout_seconds=int(heartbeat_timeout_s))
             else:
                 jax.distributed.initialize(
                     coordinator_address=coordinator,
                     num_processes=num_processes, process_id=process_id,
                     initialization_timeout=int(timeout))
             return
+        except TypeError:
+            # a wrong argument is a programming error, not a slow
+            # coordinator: retrying it would only mislabel it
+            raise
         except Exception as e:     # noqa: BLE001 — retried, then re-raised
             last = e
             try:                   # drop any partially-initialized state
@@ -568,6 +566,7 @@ class MeshRunResult:
     nnz: np.ndarray
     comm_bytes_per_round: float
     worker_ids: Tuple[int, ...]       # workers this process owned
+    worker_devices: Tuple[int, ...]   # device id holding each of them
     seconds: float
     process_id: int
     num_processes: int
@@ -716,5 +715,8 @@ def run_mesh(obj, reg, data, y, w0, cfg, spec: Optional[MeshSpec] = None, *,
     return MeshRunResult(
         w=np.asarray(w), values=np.asarray(values), nnz=np.asarray(nnzs),
         comm_bytes_per_round=comm_bytes_per_round(d),
-        worker_ids=owned, seconds=seconds,
+        worker_ids=owned,
+        worker_devices=tuple(int(_worker_devices(mesh, axis)[w][0].id)
+                             for w in owned),
+        seconds=seconds,
         process_id=jax.process_index(), num_processes=jax.process_count())

@@ -717,6 +717,8 @@ def main(argv=None) -> int:
                          "the schedule's parent-side events)")
     if args.spawn is not None:
         return _spawn(args)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return _run_rank(args)
 
 

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
-from repro import compat
 from repro.core.prox import Regularizer
 from repro.optim import optimizers as opt
 
@@ -220,9 +219,9 @@ def make_pscope_train_step(model, mesh, cfg: PScopeDLConfig,
     # shard_map: manual over worker axes only; model/fsdp axes stay auto
     in_specs = (P(), P(), P(None, waxes), P())
     out_specs = (P(), P(), P())
-    sharded = compat.shard_map(body, mesh=mesh,
-                               in_specs=in_specs, out_specs=out_specs,
-                               axis_names=set(waxes), check_vma=False)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=in_specs, out_specs=out_specs,
+                            axis_names=set(waxes), check_vma=False)
 
     @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
     def train_step(params, state, batch, key):

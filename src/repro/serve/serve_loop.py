@@ -62,6 +62,10 @@ class BatchedServer:
                     pos = jnp.asarray(self.pos)
                     logits, self.cache = self._decode(
                         self.params, self.cache, jnp.asarray(toks), pos)
+                    # wait for the step before the next one: with the
+                    # CPU runtime's async dispatch (jax 0.9) unawaited
+                    # prefill steps gave run-to-run different tokens
+                    jax.block_until_ready(self.cache)
                     self.pos[slot] += 1
 
     def step(self) -> bool:

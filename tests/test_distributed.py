@@ -7,7 +7,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -67,11 +66,6 @@ def test_pscope_distributed_equals_simulation(driver):
     assert "OK" in out
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map (axis_names=) needs modern "
-           "jax.shard_map; the jax<0.5 auto= fallback trips XLA's "
-           "IsManualSubgroup check on this mesh")
 def test_pscope_dl_step_collective_structure():
     """On a (pod,data,model) mesh the pSCOPE DL step's cross-pod traffic
     is exactly the two phase all-reduces (z + averaging), while the

@@ -214,6 +214,46 @@ def test_pallas_epoch_kernel_matches_jnp(monkeypatch, b, regime):
                                atol=5e-6, rtol=1e-4)
 
 
+@pytest.mark.parametrize("b", [1, 3])
+def test_pallas_epoch_kernel_duplicate_columns(monkeypatch, b):
+    """Rows with repeated columns: the kernel's fold into representative
+    slots == the jnp scan's segment-sum."""
+    rng = np.random.RandomState(5)
+    n_k, d, k, M = 12, 97, 9, 20
+    vals, cols = _random_shard(rng, n_k, d, k)
+    y = jnp.asarray(np.sign(rng.randn(n_k)).astype(np.float32))
+    w = jnp.asarray(rng.randn(d).astype(np.float32) * 0.1)
+    z = jnp.asarray(rng.randn(d).astype(np.float32) * 0.02)
+    idx = jnp.asarray(rng.randint(0, n_k, size=(M, b)), jnp.int32)
+    run = lambda: _lazy_inner_loop(logistic_h_prime, Regularizer(1e-2, 1e-3),
+                                   0.3, w, w, z, vals, cols, y, idx)
+    ref = run()
+    monkeypatch.setenv("USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_SPARSE_INNER_KERNEL", "1")
+    np.testing.assert_allclose(np.asarray(run()), np.asarray(ref),
+                               atol=5e-6, rtol=1e-4)
+
+
+@pytest.mark.parametrize("b", [1, 2])
+def test_pallas_epoch_kernel_bf16_vals(monkeypatch, b):
+    """Encoded shards: the kernel decodes uint16 bf16 bits in VMEM and
+    matches the jnp scan, which decodes them at operand-pack time."""
+    from repro.data.sparse import bf16_bits_to_f32
+    from repro.datasets.codec import bf16_encode
+    reg = Regularizer(1e-2, 1e-3)
+    csr, y, w, z, idx = _epoch_args(b=b, density=0.1)
+    bits = jnp.asarray(bf16_encode(np.asarray(csr.vals)))
+    statics = plan_mod.shard_statics(bf16_bits_to_f32(bits), csr.cols,
+                                     with_member=False)
+    run = lambda: _lazy_inner_loop(logistic_h_prime, reg, 0.3, w, w, z,
+                                   bits, csr.cols, y, idx, statics=statics)
+    ref = run()
+    monkeypatch.setenv("USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_SPARSE_INNER_KERNEL", "1")
+    np.testing.assert_allclose(np.asarray(run()), np.asarray(ref),
+                               atol=5e-6, rtol=1e-4)
+
+
 def test_use_pallas_modes_agree(monkeypatch):
     """USE_PALLAS=0 (pure jnp) and =1 produce the same fused trajectory."""
     reg = Regularizer(1e-3, 1e-3)

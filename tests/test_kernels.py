@@ -69,6 +69,33 @@ def test_fused_diff_equals_four_operand():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("kernel", ["lazy_prox", "fused_prox_svrg",
+                                    "fused_prox_svrg_diff"])
+def test_elementwise_kernels_cover_a_ragged_grid(kernel):
+    """d = 47,236 (rcv1) is 376 tile rows: one whole 256-row block and a
+    partial one.  Every coordinate, the last block's included, matches
+    the oracle."""
+    d = 47_236
+    rng = np.random.RandomState(6)
+    mk = lambda: jnp.asarray(rng.randn(d).astype(np.float32))
+    kw = dict(eta=0.1, lam1=1e-3, lam2=5e-3)
+    if kernel == "lazy_prox":
+        u, z = mk(), mk() * 0.02
+        q = jnp.asarray(rng.randint(0, 64, d).astype(np.int32))
+        got = ops.lazy_prox(u, z, q, **kw)
+        want = ref.lazy_prox_ref(u, z, q, **kw)
+    elif kernel == "fused_prox_svrg":
+        args = (mk(), mk(), mk(), mk())
+        got = ops.fused_prox_svrg(*args, **kw)
+        want = ref.fused_prox_svrg_ref(*args, **kw)
+    else:
+        args = (mk(), mk(), mk())
+        got = ops.fused_prox_svrg_diff(*args, **kw)
+        want = ref.fused_prox_svrg_diff_ref(*args, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
 @given(st.floats(1e-3, 1.0), st.floats(0.0, 1.0), st.floats(0.0, 1.0))
 @settings(max_examples=20, deadline=None)
 def test_fused_prox_svrg_hyperparams(eta, lam1, lam2):

@@ -429,11 +429,61 @@ def test_pscope_mesh_registry_comm_accounting():
         incs = np.diff(tr.comm)
         assert np.all(incs == per_round), tr.comm
         assert tr.comm[-1] == cfg.rounds * per_round
+        assert tr.meta["mesh"]["devices"] == [0, 1, 2, 3], tr.meta
         tr_lazy = solvers.run("pscope_lazy", LOGISTIC, reg, part, cfg)
         assert np.allclose(tr.values, tr_lazy.values, atol=1e-5)
         print("OK")
     """)
     assert "OK" in out
+
+
+def test_init_retries_let_a_type_error_through(monkeypatch):
+    """A wrong argument to the JAX initializer is raised at once, not
+    retried and relabelled as an unreachable coordinator."""
+    import jax
+    from repro.launch import mesh as mesh_mod
+    calls = []
+
+    def bad_initialize(**kw):
+        calls.append(kw)
+        raise TypeError("unexpected keyword argument")
+
+    monkeypatch.setattr(jax.distributed, "initialize", bad_initialize)
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        mesh_mod._init_with_retries("localhost:1", 2, 0, timeout=1.0,
+                                    retries=3, backoff_s=0.0, elastic=False,
+                                    heartbeat_timeout_s=60)
+    assert len(calls) == 1
+
+
+def test_elastic_init_arguments_match_installed_jax(monkeypatch):
+    """The elastic bring-up passes only arguments the installed JAX's
+    distributed-state initializer accepts."""
+    import inspect
+
+    from jax._src import distributed as dist
+    from repro.launch import mesh as mesh_mod
+    sig = inspect.signature(dist.State.initialize)
+    seen = {}
+
+    def checked_initialize(**kw):
+        sig.bind(dist.global_state, **kw)      # TypeError if JAX moved on
+        seen.update(kw)
+
+    monkeypatch.setattr(dist.global_state, "initialize", checked_initialize)
+    mesh_mod._init_with_retries("localhost:1", 2, 0, timeout=1.0, retries=1,
+                                backoff_s=0.0, elastic=True,
+                                heartbeat_timeout_s=86400)
+    assert seen["heartbeat_timeout_seconds"] == 86400
+
+
+def test_distributed_runtime_constructors_exist():
+    """The client-only and service-host paths build the runtime from
+    these; each accepts the heartbeat timeout they pass."""
+    from jax._src.lib import _jax
+    for name in ("get_distributed_runtime_client",
+                 "get_distributed_runtime_service"):
+        assert "heartbeat_timeout" in getattr(_jax, name).__doc__, name
 
 
 def test_comm_bytes_independent_of_n(tmp_path):
