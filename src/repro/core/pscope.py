@@ -62,8 +62,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import plan as plan_mod
-from repro.obs import roofline as obs_roofline
 from repro.core import svrg
 from repro.core.prox import Regularizer, prox_elastic_net
 from repro.core.recovery import recovery_catch_up
@@ -84,15 +84,21 @@ COMM_ALLREDUCES_PER_ROUND = 2
 # Device-side per-round counters carried through the scan when
 # `run_scanned(..., counters=True)`: cumulative over rounds, one f32
 # per name, surfaced post-hoc as `core.solvers.Trace.counters`.
-#   bytes_moved — modeled inner-epoch traffic summed over workers
-#                 (obs.roofline.inner_epoch_bytes; static per round)
 #   catch_up    — Lemma-11 catch-up replays actually executed: the sum
 #                 of the epoch plan's per-slot staleness counts q
 #   prox_skip   — autonomous prox steps deferred to the end-of-epoch
 #                 final catch-up (the plan's q_f residuals)
-#   comm_bytes  — the analytic CALL wire bytes, 2 d-vector all-reduces
-#                 per round (matches launch.mesh.comm_bytes_per_round)
-COUNTER_NAMES = ("bytes_moved", "catch_up", "prox_skip", "comm_bytes")
+COUNTER_NAMES = ("catch_up", "prox_skip")
+
+# Named scopes of an outer round's phases.  They land in the HLO
+# `op_name` metadata of every op the phase emits, so a profiler trace
+# attributes device time to a phase (docs/observability.md).  The epoch
+# kernel keeps its own name and gets no scope.
+SCOPE_ANCHOR_GRAD = "pscope.anchor_grad"   # phase 1 and its all-reduce
+SCOPE_PLAN = "pscope.plan"                 # sampling, epoch plan, statics
+SCOPE_GATHER = "pscope.gather"             # the steps' operand gathers
+SCOPE_AVERAGE = "pscope.average"           # phase 3 and its all-reduce
+SCOPE_OBJECTIVE = "pscope.objective"       # recorded P(w) and NNZ
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,8 +175,9 @@ def _inner_loop(loss_fn: Callable, reg: Regularizer, eta: float,
     """
 
     def step(u, ix):
-        Xb = jnp.take(Xk, ix, axis=0)
-        yb = jnp.take(yk, ix, axis=0)
+        with jax.named_scope(SCOPE_GATHER):
+            Xb = jnp.take(Xk, ix, axis=0)
+            yb = jnp.take(yk, ix, axis=0)
         if h_prime is not None:
             dv = svrg.linear_model_vr_diff(h_prime, u, w_anchor, Xb, yb)
             u = ops.fused_prox_svrg_diff(u, dv, z, eta=eta, lam1=reg.lam1,
@@ -225,16 +232,18 @@ def _lazy_inner_loop(h_prime: Callable, reg: Regularizer, eta: float,
     for pure L1 the two coincide.  This keeps the lazy engine bit-
     compatible with the dense path's prox convention.
     """
-    if statics is None:
-        n_k, k = cols_k.shape
-        statics = plan_mod.shard_statics(
-            vals_k, cols_k,
-            with_member=plan_mod.default_with_member(
-                n_k, k, inner_batch=idx.shape[1]))
     d = u0.shape[0]
-    eplan = plan_mod.build_epoch_plan(cols_k, idx, d, statics)
-    gathers = plan_mod.epoch_gathers(h_prime, w_anchor, z, vals_k, yk, idx,
-                                     eplan.cflat, statics)
+    with jax.named_scope(SCOPE_PLAN):
+        if statics is None:
+            n_k, k = cols_k.shape
+            statics = plan_mod.shard_statics(
+                vals_k, cols_k,
+                with_member=plan_mod.default_with_member(
+                    n_k, k, inner_batch=idx.shape[1]))
+        eplan = plan_mod.build_epoch_plan(cols_k, idx, d, statics)
+    with jax.named_scope(SCOPE_GATHER):
+        gathers = plan_mod.epoch_gathers(h_prime, w_anchor, z, vals_k, yk,
+                                         idx, eplan.cflat, statics)
     u = ops.fused_lazy_epoch(u0, z, eplan, gathers, h_prime=h_prime,
                              eta=eta, lam1=reg.lam1, lam2=reg.lam2,
                              inner_batch=idx.shape[1])
@@ -263,16 +272,18 @@ def _lazy_inner_loop_enc(h_prime: Callable, reg: Regularizer, eta: float,
     d = u0.shape[0]
     enc = EncodedCSR(vals16=vals16_k, colb=colb_k, dcols=dcols_k,
                      row_nnz=nnz_k, d=d)
-    cols_k = enc.decode_cols()
-    if statics is None:
-        n_k, k = cols_k.shape
-        statics = plan_mod.shard_statics(
-            enc.decode_vals(), cols_k,
-            with_member=plan_mod.default_with_member(
-                n_k, k, inner_batch=idx.shape[1]))
-    eplan = plan_mod.build_epoch_plan(cols_k, idx, d, statics)
-    gathers = plan_mod.epoch_gathers(h_prime, w_anchor, z, vals16_k, yk,
-                                     idx, eplan.cflat, statics)
+    with jax.named_scope(SCOPE_PLAN):
+        cols_k = enc.decode_cols()
+        if statics is None:
+            n_k, k = cols_k.shape
+            statics = plan_mod.shard_statics(
+                enc.decode_vals(), cols_k,
+                with_member=plan_mod.default_with_member(
+                    n_k, k, inner_batch=idx.shape[1]))
+        eplan = plan_mod.build_epoch_plan(cols_k, idx, d, statics)
+    with jax.named_scope(SCOPE_GATHER):
+        gathers = plan_mod.epoch_gathers(h_prime, w_anchor, z, vals16_k, yk,
+                                         idx, eplan.cflat, statics)
     u = ops.fused_lazy_epoch(u0, z, eplan, gathers, h_prime=h_prime,
                              eta=eta, lam1=reg.lam1, lam2=reg.lam2,
                              inner_batch=idx.shape[1])
@@ -283,8 +294,9 @@ def _lazy_inner_loop_enc(h_prime: Callable, reg: Regularizer, eta: float,
 
 def _epoch_plan_stats(eplan) -> Array:
     """(catch_up, prox_skip) for one epoch, read off the gather plan."""
-    return jnp.stack([jnp.sum(eplan.q.astype(jnp.float32)),
-                      jnp.sum(eplan.qf.astype(jnp.float32))])
+    with jax.named_scope(SCOPE_PLAN):
+        return jnp.stack([jnp.sum(eplan.q.astype(jnp.float32)),
+                          jnp.sum(eplan.qf.astype(jnp.float32))])
 
 
 def _lazy_inner_loop_ref(h_prime: Callable, reg: Regularizer, eta: float,
@@ -415,18 +427,17 @@ def _outer_step_core(obj: Objective, reg: Regularizer, cfg: PScopeConfig,
                      participation: Optional[Array]) -> PScopeState:
     """One dense outer iteration (unjitted core; scan-able)."""
     p, n_k, _ = Xp.shape
-    w_t, key = state.w, state.key
-    key, k_idx = jax.random.split(key)
+    w_t = state.w
 
     # --- phase 1: full gradient (the first "all-reduce") ------------------
-    local_grads = jax.vmap(lambda X, y: jax.grad(obj.loss_fn)(w_t, X, y))(Xp, yp)
-    z = jnp.mean(local_grads, axis=0)
+    with jax.named_scope(SCOPE_ANCHOR_GRAD):
+        local_grads = jax.vmap(
+            lambda X, y: jax.grad(obj.loss_fn)(w_t, X, y))(Xp, yp)
+        z = jnp.mean(local_grads, axis=0)
 
     # --- phase 2: autonomous local learning (no communication) ------------
-    idx = jax.vmap(
-        lambda k: svrg.sample_microbatches(k, n_k, cfg.inner_steps,
-                                           cfg.inner_batch)
-    )(jax.random.split(k_idx, p))
+    with jax.named_scope(SCOPE_PLAN):
+        key, idx = _sample_round(state.key, p, n_k, cfg)
     h_prime = _pick_h_prime(obj, cfg)
     inner = functools.partial(_inner_loop, obj.loss_fn, reg, cfg.eta,
                               h_prime=h_prime)
@@ -434,33 +445,21 @@ def _outer_step_core(obj: Objective, reg: Regularizer, cfg: PScopeConfig,
         Xp, yp, idx)
 
     # --- phase 3: cooperative averaging (the second "all-reduce") ---------
-    ctr = state.ctr
-    if ctr is not None:
-        d = w_t.shape[0]
-        ctr = ctr + _round_counter_increment(
-            "dense", d=d, p=p, k=d, cfg=cfg,
-            catch_up=jnp.zeros((), jnp.float32),
-            prox_skip=jnp.zeros((), jnp.float32))
-    return PScopeState(w=_average(u_final, participation), t=state.t + 1,
-                       key=key, ctr=ctr)
+    with jax.named_scope(SCOPE_AVERAGE):
+        w_next = _average(u_final, participation)
+    # the dense engine has no epoch plan: its counters stay at zero
+    return PScopeState(w=w_next, t=state.t + 1, key=key, ctr=state.ctr)
 
 
-def _round_counter_increment(path: str, *, d: int, p: int, k: int,
-                             cfg: PScopeConfig, catch_up: Array,
-                             prox_skip: Array) -> Array:
-    """One outer round's (len(COUNTER_NAMES),) counter increment.
-
-    bytes_moved and comm_bytes are static analytic constants (the same
-    models BENCH_inner_loop / BENCH_comm pin), so only the two plan
-    sums are live device values — the counter carry costs two scalar
-    reductions per round and nothing else.
-    """
-    per_worker = obs_roofline.inner_epoch_bytes(
-        path, d=d, M=cfg.inner_steps, b=cfg.inner_batch, k=k)
-    return jnp.stack([
-        jnp.full((), p * per_worker, jnp.float32),
-        catch_up, prox_skip,
-        jnp.full((), COMM_ALLREDUCES_PER_ROUND * d * 4.0, jnp.float32)])
+def _sample_round(key: Array, p: int, n_k: int, cfg: PScopeConfig):
+    """(carried key, (p, M, b) microbatch indices) of one outer round:
+    worker k draws from split(k_idx, p)[k]."""
+    key, k_idx = jax.random.split(key)
+    idx = jax.vmap(
+        lambda k: svrg.sample_microbatches(k, n_k, cfg.inner_steps,
+                                           cfg.inner_batch)
+    )(jax.random.split(k_idx, p))
+    return key, idx
 
 
 def _outer_step_lazy_core(obj: Objective, reg: Regularizer,
@@ -480,24 +479,22 @@ def _outer_step_lazy_core(obj: Objective, reg: Regularizer,
     encoded = isinstance(csr_p, EncodedCSR)
     p, n_k = yp.shape
     d = state.w.shape[0]
-    w_t, key = state.w, state.key
-    key, k_idx = jax.random.split(key)
+    w_t = state.w
 
     # --- phase 1: anchor gradient via sparse scatter-add ------------------
-    if encoded:
-        vals_p, cols_p = csr_p.decode_vals(), csr_p.decode_cols()
-    else:
-        vals_p, cols_p = csr_p.vals, csr_p.cols
-    local_grads = jax.vmap(
-        lambda v, c, y: svrg.sparse_linear_model_full_gradient(
-            h_prime, w_t, v, c, y, d))(vals_p, cols_p, yp)
-    z = jnp.mean(local_grads, axis=0)
+    with jax.named_scope(SCOPE_ANCHOR_GRAD):
+        if encoded:
+            vals_p, cols_p = csr_p.decode_vals(), csr_p.decode_cols()
+        else:
+            vals_p, cols_p = csr_p.vals, csr_p.cols
+        local_grads = jax.vmap(
+            lambda v, c, y: svrg.sparse_linear_model_full_gradient(
+                h_prime, w_t, v, c, y, d))(vals_p, cols_p, yp)
+        z = jnp.mean(local_grads, axis=0)
 
     # --- phase 2: fused lazy autonomous local learning --------------------
-    idx = jax.vmap(
-        lambda k: svrg.sample_microbatches(k, n_k, cfg.inner_steps,
-                                           cfg.inner_batch)
-    )(jax.random.split(k_idx, p))
+    with jax.named_scope(SCOPE_PLAN):
+        key, idx = _sample_round(state.key, p, n_k, cfg)
     want_stats = state.ctr is not None
     if encoded:
         inner = functools.partial(_lazy_inner_loop_enc, h_prime, reg,
@@ -531,15 +528,13 @@ def _outer_step_lazy_core(obj: Objective, reg: Regularizer,
     ctr = state.ctr
     if want_stats:
         u_final, stats_w = out          # stats_w: (p, 2) per-worker sums
-        stats = jnp.sum(stats_w, axis=0)
-        k_w = (csr_p.vals16.shape[-1] if encoded else csr_p.vals.shape[-1])
-        ctr = ctr + _round_counter_increment(
-            "fused", d=d, p=p, k=k_w, cfg=cfg,
-            catch_up=stats[0], prox_skip=stats[1])
+        with jax.named_scope(SCOPE_PLAN):
+            ctr = ctr + jnp.sum(stats_w, axis=0)
     else:
         u_final = out
-    return PScopeState(w=_average(u_final, participation), t=state.t + 1,
-                       key=key, ctr=ctr)
+    with jax.named_scope(SCOPE_AVERAGE):
+        w_next = _average(u_final, participation)
+    return PScopeState(w=w_next, t=state.t + 1, key=key, ctr=ctr)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
@@ -706,7 +701,8 @@ def _sim_trajectory_fn(obj: Objective, reg: Regularizer, cfg: PScopeConfig,
                             ctr=ctr0)
 
         def record(st):
-            base = (obj_val(st.w), jnp.sum(jnp.abs(st.w) > NNZ_TOL))
+            with jax.named_scope(SCOPE_OBJECTIVE):
+                base = (obj_val(st.w), jnp.sum(jnp.abs(st.w) > NNZ_TOL))
             return base + (st.ctr,) if with_counters else base
 
         def step_fn(st, part_t):
@@ -760,23 +756,29 @@ def run_scanned(obj: Objective, reg: Regularizer, Xp, yp: Array, w0: Array,
 
     `counters=True` additionally carries the (len(COUNTER_NAMES),)
     telemetry counters through the scan and returns them as a fourth
-    (records, 4) cumulative array — same single host transfer, same
-    values/NNZ bits (the counters never touch the iterate path; the
-    added cost is two scalar plan reductions per round).
+    (records, len(COUNTER_NAMES)) cumulative array — same single host
+    transfer, same values/NNZ bits (the counters never touch the iterate
+    path; the added cost is two scalar plan reductions per round).
+
+    The host side of a solve is three spans (`repro.obs`):
+    `solve.prepare` (the CSR view, the shard statics, the inputs),
+    `solve.dispatch` (the call of the compiled trajectory) and
+    `solve.fetch` (the host transfers, which wait for the device).
     """
-    cfg, Xp, yp, statics = _prepare_sim(obj, reg, Xp, yp, cfg)
-    p = yp.shape[0]
-    parts = _stack_participation(participation_schedule, cfg.outer_steps, p)
-    compiled = _sim_trajectory_fn(obj, reg, cfg, record_every,
-                                  bool(counters))
-    w0d = jnp.array(w0, dtype=jnp.float32, copy=True)
-    key0 = advance_key(jax.random.PRNGKey(cfg.seed), start_round)
-    if counters:
-        w, values, nnzs, ctrs = compiled(w0d, key0, Xp, yp, parts, statics)
-        return (np.asarray(w), np.asarray(values), np.asarray(nnzs),
-                np.asarray(ctrs))
-    w, values, nnzs = compiled(w0d, key0, Xp, yp, parts, statics)
-    return np.asarray(w), np.asarray(values), np.asarray(nnzs)
+    with obs.span("solve.prepare"):
+        cfg, Xp, yp, statics = _prepare_sim(obj, reg, Xp, yp, cfg)
+        p = yp.shape[0]
+        parts = _stack_participation(participation_schedule,
+                                     cfg.outer_steps, p)
+        compiled = _sim_trajectory_fn(obj, reg, cfg, record_every,
+                                      bool(counters))
+        w0d = jnp.array(w0, dtype=jnp.float32, copy=True)
+        key0 = advance_key(jax.random.PRNGKey(cfg.seed), start_round)
+    with obs.span("solve.dispatch"):
+        out = compiled(w0d, key0, Xp, yp, parts, statics)
+    # the first transfer waits for the device to finish the trajectory
+    with obs.span("solve.fetch"):
+        return tuple(np.asarray(x) for x in out)
 
 
 def run(obj: Objective, reg: Regularizer, Xp, yp: Array, w0: Array,
@@ -883,24 +885,30 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
                else _pick_h_prime(obj, cfg))
     p = mesh.shape[axis]
 
+    def local_idx(key, n_k):
+        # The per-worker key is split(key, p)[worker] — the SAME
+        # derivation simulation mode uses — so worker k draws the
+        # identical sample sequence in both modes and a mesh trajectory
+        # matches run_scanned's within fp32 reassociation (the
+        # multi-host equivalence tests pin this; fold_in(key, widx)
+        # would decorrelate the modes).
+        with jax.named_scope(SCOPE_PLAN):
+            widx = jax.lax.axis_index(axis)
+            k_local = jnp.take(jax.random.split(key, p), widx, axis=0)
+            return svrg.sample_microbatches(k_local, n_k, cfg.inner_steps,
+                                            cfg.inner_batch)
+
     def body(w_t, key, Xk_or_vals, yk, cols_k=None, statics=None):
         # phase 1: one all-reduce for the anchor (full) gradient
-        if lazy:
-            z_local = svrg.sparse_linear_model_full_gradient(
-                h_prime, w_t, Xk_or_vals, cols_k, yk, w_t.shape[0])
-        else:
-            z_local = jax.grad(obj.loss_fn)(w_t, Xk_or_vals, yk)
-        z = jax.lax.pmean(z_local, axis)
-        # phase 2: local inner loop, no DP collectives.  The per-worker
-        # key is split(key, p)[worker] — the SAME derivation simulation
-        # mode uses — so worker k draws the identical sample sequence
-        # in both modes and a mesh trajectory matches run_scanned's
-        # within fp32 reassociation (the multi-host equivalence tests
-        # pin this; fold_in(key, widx) would decorrelate the modes).
-        widx = jax.lax.axis_index(axis)
-        k_local = jnp.take(jax.random.split(key, p), widx, axis=0)
-        idx = svrg.sample_microbatches(k_local, Xk_or_vals.shape[0],
-                                       cfg.inner_steps, cfg.inner_batch)
+        with jax.named_scope(SCOPE_ANCHOR_GRAD):
+            if lazy:
+                z_local = svrg.sparse_linear_model_full_gradient(
+                    h_prime, w_t, Xk_or_vals, cols_k, yk, w_t.shape[0])
+            else:
+                z_local = jax.grad(obj.loss_fn)(w_t, Xk_or_vals, yk)
+            z = jax.lax.pmean(z_local, axis)
+        # phase 2: local inner loop, no DP collectives
+        idx = local_idx(key, Xk_or_vals.shape[0])
         if lazy:
             u = _lazy_inner_loop(h_prime, reg, cfg.eta, w_t, w_t, z,
                                  Xk_or_vals, cols_k, yk, idx,
@@ -909,7 +917,8 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
             u = _inner_loop(obj.loss_fn, reg, cfg.eta, w_t, w_t, z,
                             Xk_or_vals, yk, idx, h_prime=h_prime)
         # phase 3: one all-reduce to average iterates
-        return jax.lax.pmean(u, axis)
+        with jax.named_scope(SCOPE_AVERAGE):
+            return jax.lax.pmean(u, axis)
 
     def body_enc(w_t, key, vals16, y, colb, dcols, nnz):
         # encoded-shard variant: the registered device operands are the
@@ -920,16 +929,15 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
         d = w_t.shape[0]
         enc = EncodedCSR(vals16=vals16, colb=colb, dcols=dcols,
                          row_nnz=nnz, d=d)
-        z_local = svrg.sparse_linear_model_full_gradient(
-            h_prime, w_t, enc.decode_vals(), enc.decode_cols(), y, d)
-        z = jax.lax.pmean(z_local, axis)
-        widx = jax.lax.axis_index(axis)
-        k_local = jnp.take(jax.random.split(key, p), widx, axis=0)
-        idx = svrg.sample_microbatches(k_local, y.shape[0],
-                                       cfg.inner_steps, cfg.inner_batch)
+        with jax.named_scope(SCOPE_ANCHOR_GRAD):
+            z_local = svrg.sparse_linear_model_full_gradient(
+                h_prime, w_t, enc.decode_vals(), enc.decode_cols(), y, d)
+            z = jax.lax.pmean(z_local, axis)
+        idx = local_idx(key, y.shape[0])
         u = _lazy_inner_loop_enc(h_prime, reg, cfg.eta, w_t, w_t, z,
                                  vals16, colb, dcols, nnz, y, idx)
-        return jax.lax.pmean(u, axis)
+        with jax.named_scope(SCOPE_AVERAGE):
+            return jax.lax.pmean(u, axis)
 
     def make_shard_body(with_statics: bool, encoded: bool = False):
         n_data = 5 if encoded else (3 if lazy else 2)
@@ -950,7 +958,8 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
     if lazy:
         def outer_step(state: PScopeState, csr, y: Array,
                        statics=None) -> PScopeState:
-            key, sub = jax.random.split(state.key)
+            with jax.named_scope(SCOPE_PLAN):
+                key, sub = jax.random.split(state.key)
             if isinstance(csr, EncodedCSR):
                 # statics are rebuilt inside the epoch on this path (a
                 # data-only precompute; identical plans either way)
@@ -967,7 +976,8 @@ def make_distributed_outer_step_core(obj: Objective, reg: Regularizer,
     else:
         def outer_step(state: PScopeState, X: Array, y: Array,
                        statics=None) -> PScopeState:
-            key, sub = jax.random.split(state.key)
+            with jax.named_scope(SCOPE_PLAN):
+                key, sub = jax.random.split(state.key)
             w_next = make_shard_body(False)(state.w, sub, X, y)
             return PScopeState(w=w_next, t=state.t + 1, key=key)
 
@@ -1008,7 +1018,8 @@ def _distributed_trajectory_fn(obj: Objective, reg: Regularizer,
         obj_val = _objective_value_device(obj, reg, X, y)
 
         def record(st):
-            return obj_val(st.w), jnp.sum(jnp.abs(st.w) > NNZ_TOL)
+            with jax.named_scope(SCOPE_OBJECTIVE):
+                return obj_val(st.w), jnp.sum(jnp.abs(st.w) > NNZ_TOL)
 
         def step_fn(st, _):
             return step_core(st, X, y, statics)
@@ -1034,15 +1045,20 @@ def run_distributed_scanned(obj: Objective, reg: Regularizer, X, y: Array,
     trajectory's tail from the same iterate.
 
     Returns (w_T, values, nnz) as numpy arrays of T // record_every + 1
-    entries.
+    entries.  Its host spans are `mesh.prepare` (the sharded statics and
+    the inputs), `mesh.dispatch` and `mesh.fetch`, as in `run_scanned`.
     """
-    cfg, X, statics = _prepare_distributed(obj, reg, X, y, cfg, mesh, axis)
-    compiled = _distributed_trajectory_fn(obj, reg, cfg, mesh, axis,
-                                          record_every)
-    w0d = jnp.array(w0, dtype=jnp.float32, copy=True)
-    key0 = advance_key(jax.random.PRNGKey(cfg.seed), start_round)
-    w, values, nnzs = compiled(w0d, key0, X, y, statics)
-    return np.asarray(w), np.asarray(values), np.asarray(nnzs)
+    with obs.span("mesh.prepare"):
+        cfg, X, statics = _prepare_distributed(obj, reg, X, y, cfg, mesh,
+                                               axis)
+        compiled = _distributed_trajectory_fn(obj, reg, cfg, mesh, axis,
+                                              record_every)
+        w0d = jnp.array(w0, dtype=jnp.float32, copy=True)
+        key0 = advance_key(jax.random.PRNGKey(cfg.seed), start_round)
+    with obs.span("mesh.dispatch"):
+        out = compiled(w0d, key0, X, y, statics)
+    with obs.span("mesh.fetch"):
+        return tuple(np.asarray(x) for x in out)
 
 
 # ---------------------------------------------------------------------------

@@ -87,9 +87,9 @@ class Trace:
     heldout: Dict[str, float] = dataclasses.field(default_factory=dict)
     meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
     # named cumulative counter series, index-aligned with `values`
-    # (e.g. the scanned drivers' device-side bytes_moved / catch_up /
-    # prox_skip / comm_bytes — see pscope.COUNTER_NAMES); empty unless
-    # the adapter feeds them via `record_history(..., counters=...)`
+    # (e.g. the scanned drivers' device-side catch_up / prox_skip —
+    # see pscope.COUNTER_NAMES); empty unless the adapter feeds them
+    # via `record_history(..., counters=...)`
     counters: Dict[str, List[float]] = dataclasses.field(
         default_factory=dict)
     _t0: Optional[float] = dataclasses.field(default=None, repr=False)
@@ -343,17 +343,21 @@ def run(solver: str, obj: Objective, reg: Regularizer, part: Partition,
     """The uniform entry point: run `solver` on (obj, reg, part).
 
     Returns a validated `Trace`; `trace.w_final` holds the last iterate.
+    The whole call is the `solve.<solver>` span (`repro.obs`).
     """
     spec = get(solver)
     cfg = config if config is not None else SolverConfig()
-    trace = Trace(solver=spec.name, objective=obj.name, partition=part.name,
-                  p=part.p, d=part.d)
-    trace.start()
-    trace.w_final = spec.run_fn(obj, reg, part, cfg, trace)
-    if cfg.estimate_gamma:
-        trace.gamma = estimate_partition_gamma(
-            obj, reg, part, num_samples=cfg.gamma_samples, seed=cfg.seed)
-    return trace.validate()
+    with obs.span(f"solve.{spec.name}", rounds=cfg.rounds, p=part.p,
+                  d=part.d):
+        trace = Trace(solver=spec.name, objective=obj.name,
+                      partition=part.name, p=part.p, d=part.d)
+        trace.start()
+        trace.w_final = spec.run_fn(obj, reg, part, cfg, trace)
+        if cfg.estimate_gamma:
+            trace.gamma = estimate_partition_gamma(
+                obj, reg, part, num_samples=cfg.gamma_samples,
+                seed=cfg.seed)
+        return trace.validate()
 
 
 def estimate_partition_gamma(obj: Objective, reg: Regularizer,
@@ -421,7 +425,7 @@ def _pscope_config(obj, reg, part, cfg, inner_path: str):
 
 def _round_offsets(n_records: int, total_seconds: float) -> List[float]:
     """The linear per-round time attribution `record_history` uses —
-    reused to timestamp counter events inside the solve span."""
+    reused to timestamp counter events across the trajectory's run."""
     rounds = max(n_records - 1, 1)
     return [total_seconds * i / rounds for i in range(n_records)]
 
@@ -429,7 +433,7 @@ def _round_offsets(n_records: int, total_seconds: float) -> List[float]:
 def _emit_counter_events(counters: Dict[str, Any], offsets: List[float],
                          t0_s: float) -> None:
     """Emit each cumulative series as obs counter samples, timestamped
-    at the solve span start + the per-round attribution offsets."""
+    at the trajectory's start + the per-round attribution offsets."""
     for name, series in counters.items():
         for val, off in zip(series, offsets):
             obs.counter(name, float(val), ts_s=t0_s + off)
@@ -450,20 +454,16 @@ def _run_pscope_scanned(obj, reg, Xp, yp, w0, pcfg, trace, eval_data=None,
     `SolverConfig.extras["counters"]`) carries the device-side
     telemetry counters through the scan — same single host transfer,
     values/NNZ bit-identical either way — and surfaces them as
-    `trace.counters` plus per-round obs counter events inside the
-    solve span; the host-side fan-out is charged as recording
-    overhead."""
+    `trace.counters` plus per-round obs counter events timestamped
+    across the trajectory's run; the host-side fan-out is charged as
+    recording overhead."""
     t0 = time.perf_counter()
-    with obs.span(f"solve.{trace.solver}", rounds=pcfg.outer_steps,
-                  inner_path=pcfg.inner_path, p=trace.p,
-                  d=trace.d) as sp:
-        if counters:
-            w, values, nnzs, ctrs = pscope.run_scanned(
-                obj, reg, Xp, yp, w0, pcfg, counters=True)
-        else:
-            w, values, nnzs = pscope.run_scanned(obj, reg, Xp, yp, w0,
-                                                 pcfg)
-            ctrs = None
+    if counters:
+        w, values, nnzs, ctrs = pscope.run_scanned(
+            obj, reg, Xp, yp, w0, pcfg, counters=True)
+    else:
+        w, values, nnzs = pscope.run_scanned(obj, reg, Xp, yp, w0, pcfg)
+        ctrs = None
     total = time.perf_counter() - t0
     cdict = None
     if ctrs is not None:
@@ -474,7 +474,7 @@ def _run_pscope_scanned(obj, reg, Xp, yp, w0, pcfg, trace, eval_data=None,
     if cdict is not None:
         t_emit = time.perf_counter()
         _emit_counter_events(cdict, _round_offsets(len(values), total),
-                             sp.t0)
+                             t0)
         trace.charge_overhead(time.perf_counter() - t_emit)
     if eval_data is not None:
         t_eval = time.perf_counter()
@@ -541,29 +541,17 @@ def _run_pscope_mesh(obj, reg, part, cfg, trace):
     pcfg = _pscope_config(obj, reg, part, cfg, inner_path)
     data = part.Xp if inner_path == "dense" else part.csr_p
     spec = cfg.extras.get("mesh_spec")
-    with obs.span("solve.pscope_mesh", rounds=pcfg.outer_steps,
-                  inner_path=pcfg.inner_path, p=trace.p,
-                  d=trace.d) as sp:
-        res = mesh_mod.run_mesh(obj, reg, data, part.yp, _w0(part, cfg),
-                                pcfg, spec)
+    res = mesh_mod.run_mesh(obj, reg, data, part.yp, _w0(part, cfg), pcfg,
+                            spec)
     trace.meta["comm_units"] = "bytes"
     trace.meta["mesh"] = {"num_processes": res.num_processes,
                           "local_worker_ids": list(res.worker_ids),
                           "devices": list(res.worker_devices)}
+    # `run_mesh` puts the per-round wire bytes on the timeline as the
+    # `comm_bytes` counter; Trace.comm holds the same analytic series
     trace.record_history(res.values, res.nnz,
                          comm_per_record=res.comm_bytes_per_round,
                          total_seconds=res.seconds)
-    # Per-round wire-byte counters.  The mesh step's collectives live
-    # inside the compiled scan, so the series is the same analytic
-    # model `Trace.comm` records — emitted FROM trace.comm so the
-    # timeline counter and the trace agree exactly, by construction.
-    t_emit = time.perf_counter()
-    comm_series = list(trace.comm[-len(res.values):])
-    trace.counters.setdefault("comm_bytes", []).extend(comm_series)
-    _emit_counter_events({"comm_bytes": comm_series},
-                         _round_offsets(len(res.values), res.seconds),
-                         sp.t0)
-    trace.charge_overhead(time.perf_counter() - t_emit)
     eval_data = cfg.extras.get("eval")
     if eval_data is not None:
         t_eval = time.perf_counter()

@@ -30,13 +30,18 @@ Design constraints, in order:
 The module is stdlib-only and never imports jax: importing it costs
 nothing, and the recording path stays cheap enough to leave on always
 (events are bounded by `MAX_EVENTS`; overflow increments a drop
-counter instead of growing without bound).
+counter instead of growing without bound).  A span opened in a process
+that has imported jax also enters `jax.profiler.TraceAnnotation`: with
+no profiler active that costs about a microsecond, and under
+`jax.profiler.trace` the span appears by name in the `.xplane.pb`,
+on the clock of the device's ops.
 """
 from __future__ import annotations
 
 import glob as _glob
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional, Union
@@ -51,22 +56,34 @@ class Span:
     Exposes `t0` (perf_counter seconds at entry) so callers can stamp
     derived events — e.g. per-round counter series linearly attributed
     inside a compiled-solve span — onto the same clock.
+
+    Where jax is already imported, the span also opens a
+    `jax.profiler.TraceAnnotation` of its name, so that a profiler
+    trace holds it on the device's clock; its args stay here.
     """
 
-    __slots__ = ("_col", "name", "args", "t0")
+    __slots__ = ("_col", "name", "args", "t0", "_annotation")
 
     def __init__(self, col: "Collector", name: str, args: Dict[str, Any]):
         self._col = col
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
         args = dict(self.args)
         if exc_type is not None:
             args["error"] = exc_type.__name__

@@ -1,7 +1,14 @@
 """Telemetry subsystem tests: span/counter collection, Chrome-trace
 schema, spool merge, device counters (bit-identical trajectories,
-bounded overhead), and the roofline annotation math."""
+bounded overhead), the round phases' named scopes, program spans on the
+profiler's clock, and the roofline annotation math."""
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
 import threading
 
 import numpy as np
@@ -161,25 +168,11 @@ def test_counters_never_perturb_trajectory(small_problem, inner_path):
     assert np.all(np.diff(ctrs, axis=0) >= 0)
 
 
-def test_comm_bytes_counter_is_exact(small_problem):
-    Xp, yp = small_problem
-    d = Xp.shape[-1]
-    reg = Regularizer(1e-3, 1e-3)
-    cfg = PScopeConfig(eta=0.5, inner_steps=16, inner_batch=2,
-                       outer_steps=6, inner_path="lazy")
-    _, _, _, ctrs = pscope.run_scanned(
-        LOGISTIC, reg, Xp, yp, np.zeros(d, np.float32), cfg, counters=True)
-    j = pscope.COUNTER_NAMES.index("comm_bytes")
-    want = np.arange(cfg.outer_steps + 1, dtype=np.float64) \
-        * pscope.COMM_ALLREDUCES_PER_ROUND * d * 4.0
-    assert np.array_equal(ctrs[:, j], want)
-
-
 def test_trace_counters_match_trace_comm(small_problem):
-    """The timeline's comm_bytes series and Trace.comm agree exactly:
-    Trace.comm counts all-reduces (2/round), the counter carries the
-    wire bytes of the same all-reduces (x d x 4), and the emitted
-    counter events repeat the Trace.counters series verbatim."""
+    """The timeline's catch_up series and Trace.counters agree exactly:
+    the adapter surfaces the device-side cumulative series index-aligned
+    with Trace.values and Trace.comm, and the emitted counter events
+    repeat it verbatim."""
     Xp, yp = small_problem
     d = Xp.shape[-1]
     X = Xp.reshape(-1, d)
@@ -191,11 +184,14 @@ def test_trace_counters_match_trace_comm(small_problem):
     obs.reset()
     tr = solvers.run("pscope_lazy", LOGISTIC, Regularizer(1e-3, 1e-3),
                      part, solvers.SolverConfig(rounds=4, eta=0.5))
-    assert tr.counters["comm_bytes"] == [c * d * 4.0 for c in tr.comm]
+    assert set(tr.counters) == set(pscope.COUNTER_NAMES)
+    series = tr.counters["catch_up"]
+    assert len(series) == len(tr.values) == len(tr.comm)
+    assert series[0] == 0.0 and series[-1] > 0.0
+    assert all(b >= a for a, b in zip(series, series[1:]))
     ctr_evs = [e for e in obs.get_collector().events()
-               if e["ph"] == "C" and e["name"] == "comm_bytes"]
-    assert ([e["args"]["comm_bytes"] for e in ctr_evs]
-            == tr.counters["comm_bytes"])
+               if e["ph"] == "C" and e["name"] == "catch_up"]
+    assert [e["args"]["catch_up"] for e in ctr_evs] == series
     obs.reset()
 
 
@@ -242,6 +238,157 @@ def test_solvers_counters_opt_out(small_problem):
     tr = solvers.run("pscope_lazy", LOGISTIC, Regularizer(1e-3, 1e-3),
                      part, cfg)
     assert tr.counters == {}
+
+
+# ---------------------------------------------------------------------------
+# named scopes of the round's phases; program spans on the profiler clock
+# ---------------------------------------------------------------------------
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCOPES = {pscope.SCOPE_ANCHOR_GRAD, pscope.SCOPE_PLAN, pscope.SCOPE_GATHER,
+          pscope.SCOPE_AVERAGE, pscope.SCOPE_OBJECTIVE}
+
+# one 4-device process: the mesh trajectory's lowered HLO and the host
+# spans of one `run_mesh` solve
+_MESH_CODE = """
+    import json, re
+    import jax, jax.numpy as jnp, numpy as np
+    from repro import obs
+    from repro.core import LOGISTIC, PScopeConfig, Regularizer, pscope
+    from repro.core.partition import uniform_partition, stack_partition
+    from repro.data.sparse import dense_to_csr
+    from repro.data.synthetic import make_sparse_classification
+    from repro.launch.mesh import MeshSpec, run_mesh
+
+    X, y, _ = make_sparse_classification(256, 64, density=0.1, seed=0)
+    idx = uniform_partition(jax.random.PRNGKey(0), 256, 4)
+    Xp, yp = stack_partition(jnp.asarray(X), jnp.asarray(y), idx)
+    reg = Regularizer(1e-3, 1e-3)
+    cfg = PScopeConfig(eta=0.5, inner_steps=16, inner_batch=2,
+                       outer_steps=3, inner_path="lazy")
+    mesh = jax.make_mesh((4,), ("data",))
+    Xf, yf = dense_to_csr(Xp.reshape(-1, 64)), yp.reshape(-1)
+    cfg, Xf, statics = pscope._prepare_distributed(LOGISTIC, reg, Xf, yf,
+                                                   cfg, mesh, "data")
+    fn = pscope._distributed_trajectory_fn(LOGISTIC, reg, cfg, mesh, "data")
+    low = fn.lower(jnp.zeros(64), jax.random.PRNGKey(0), Xf, yf, statics)
+    obs.reset()
+    spec = MeshSpec.for_workers(4)
+    run_mesh(LOGISTIC, reg, pscope._as_csr_shards(Xp, yp)[0], yp,
+             np.zeros(64, np.float32), cfg, spec)
+    spans = [[e["name"], e["ts"], e["ts"] + e["dur"]]
+             for e in obs.get_collector().events() if e["ph"] == "X"]
+    print(json.dumps({"hlo": low.as_text(dialect="hlo", debug_info=True),
+                      "spans": spans}))
+"""
+
+
+def _scopes_in(hlo_text: str) -> set:
+    """The pscope.* scope names in an HLO text's op_name metadata."""
+    return {m for name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for m in re.findall(r"pscope\.\w+", name)}
+
+
+@pytest.fixture(scope="module")
+def mesh_run():
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c",
+                           textwrap.dedent(_MESH_CODE)],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2500:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _sim_hlo(small_problem, inner_path: str) -> str:
+    Xp, yp = small_problem
+    cfg = PScopeConfig(eta=0.5, inner_steps=16, inner_batch=2,
+                       outer_steps=3, inner_path=inner_path)
+    reg = Regularizer(1e-3, 1e-3)
+    cfg, Xq, yq, statics = pscope._prepare_sim(LOGISTIC, reg, Xp, yp, cfg)
+    fn = pscope._sim_trajectory_fn(LOGISTIC, reg, cfg, 1, True)
+    low = fn.lower(jnp.zeros(Xp.shape[-1]), jax.random.PRNGKey(0), Xq, yq,
+                   None, statics)
+    return low.as_text(dialect="hlo", debug_info=True)
+
+
+@pytest.mark.parametrize("trajectory", ["lazy", "dense", "mesh"])
+def test_round_phases_are_named_in_hlo(small_problem, request, trajectory):
+    """Every phase of an outer round carries its named scope into the
+    HLO op_name metadata, in the simulated lazy and dense trajectories
+    and in the 4-device shard_map trajectory."""
+    if trajectory == "mesh":
+        hlo = request.getfixturevalue("mesh_run")["hlo"]
+    else:
+        hlo = _sim_hlo(small_problem, trajectory)
+    assert _scopes_in(hlo) == SCOPES
+
+
+def _within(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_solve_host_spans_nest_in_the_solve_span(small_problem):
+    Xp, yp = small_problem
+    from repro.core.partition import make_partition
+    X = Xp.reshape(-1, Xp.shape[-1])
+    part = make_partition(X, yp.reshape(-1),
+                          jnp.arange(X.shape[0]).reshape(4, -1), "uniform")
+    obs.reset()
+    solvers.run("pscope_lazy", LOGISTIC, Regularizer(1e-3, 1e-3), part,
+                solvers.SolverConfig(rounds=2, eta=0.5))
+    xs = {e["name"]: [e["name"], e["ts"], e["ts"] + e["dur"]]
+          for e in obs.get_collector().events() if e["ph"] == "X"}
+    obs.reset()
+    steps = [xs["solve.prepare"], xs["solve.dispatch"], xs["solve.fetch"]]
+    assert all(_within(s, xs["solve.pscope_lazy"]) for s in steps)
+    assert steps[0][2] <= steps[1][1] and steps[1][2] <= steps[2][1]
+
+
+def test_mesh_host_spans_nest_in_the_mesh_solve_span(mesh_run):
+    xs = {n: [n, s, e] for n, s, e in mesh_run["spans"]}
+    assert {"mesh.shards", "mesh.solve", "mesh.prepare", "mesh.dispatch",
+            "mesh.fetch"} <= set(xs)
+    steps = [xs["mesh.prepare"], xs["mesh.dispatch"], xs["mesh.fetch"]]
+    assert all(_within(s, xs["mesh.solve"]) for s in steps)
+    assert xs["mesh.shards"][2] <= xs["mesh.solve"][1]
+    assert steps[0][2] <= steps[1][1] and steps[1][2] <= steps[2][1]
+
+
+def test_span_reaches_the_profiler_trace(tmp_path):
+    """A program span opened under `jax.profiler.trace` is a host event
+    of its name in the .xplane.pb; its args stay in the collector."""
+    from jax.profiler import ProfileData
+    obs.reset()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("solve.prepare", p=4):
+            jnp.arange(8.0).sum().block_until_ready()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [e for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    assert "solve.prepare" in {e.name for e in host}
+    ev, = [e for e in obs.get_collector().events()
+           if e["name"] == "solve.prepare"]
+    assert ev["args"] == {"p": 4}
+    obs.reset()
+
+
+def test_obs_imports_without_jax():
+    code = textwrap.dedent("""
+        import sys
+        from repro import obs
+        with obs.span("ingest.parse"):
+            pass
+        assert [e["name"] for e in obs.get_collector().events()] == [
+            "ingest.parse"]
+        assert not [m for m in sys.modules if m.split(".")[0] == "jax"]
+    """)
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2500:]
 
 
 # ---------------------------------------------------------------------------
