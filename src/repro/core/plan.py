@@ -149,6 +149,29 @@ class EpochPlan(NamedTuple):
     qf: Array
 
 
+def padded_slot_width(k: int) -> int:
+    """The fused epoch kernel's slots per sample: k rounded up to a
+    multiple of 128 lanes."""
+    return -(-k // 128) * 128
+
+
+def fold_bounds(rep: Array, inner_batch: int) -> Array:
+    """(M,) int32 trip counts of the fused epoch kernel's duplicate fold.
+
+    Each step's count is one past its last non-representative slot
+    (``rep[s] != s``), in the kernel's padded slot space where slot i
+    of sample j sits at ``j * padded_slot_width(k) + i``; 0 for a step
+    whose columns are distinct.  Every slot at or past it represents
+    itself, so the fold adds nothing there.
+    """
+    M, S = rep.shape
+    k = S // inner_batch
+    slot = jnp.arange(S, dtype=jnp.int32)
+    padded = slot // k * padded_slot_width(k) + slot % k
+    return jnp.max(jnp.where(rep != slot, padded + 1, 0),
+                   axis=1).astype(jnp.int32)
+
+
 def build_epoch_plan(cols_k: Array, idx: Array, d: int,
                      statics: Optional[ShardStatics] = None) -> EpochPlan:
     """Hoist the whole epoch's catch-up bookkeeping out of the scan.

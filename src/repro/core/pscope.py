@@ -88,7 +88,10 @@ COMM_ALLREDUCES_PER_ROUND = 2
 #                 of the epoch plan's per-slot staleness counts q
 #   prox_skip   — autonomous prox steps deferred to the end-of-epoch
 #                 final catch-up (the plan's q_f residuals)
-COUNTER_NAMES = ("catch_up", "prox_skip")
+#   dup_fold    — the fused epoch kernel's duplicate-fold iterations:
+#                 the sum of the steps' `plan.fold_bounds` (0 when no
+#                 sampled row repeats a column)
+COUNTER_NAMES = ("catch_up", "prox_skip", "dup_fold")
 
 # Named scopes of an outer round's phases.  They land in the HLO
 # `op_name` metadata of every op the phase emits, so a profiler trace
@@ -204,10 +207,11 @@ def _lazy_inner_loop(h_prime: Callable, reg: Regularizer, eta: float,
                      with_stats: bool = False):
     """M fused inner steps touching only each microbatch's columns.
 
-    `with_stats=True` additionally returns a (2,) f32 of this epoch's
+    `with_stats=True` additionally returns a (3,) f32 of this epoch's
     plan-derived work counters — (sum of catch-up replays q, sum of
-    final-catch-up residuals q_f) — read straight off the already-built
-    `EpochPlan`, so the iterate math is untouched (see COUNTER_NAMES).
+    final-catch-up residuals q_f, duplicate-fold iterations) — read
+    straight off the already-built `EpochPlan`, so the iterate math is
+    untouched (see COUNTER_NAMES).
 
     All catch-up bookkeeping — which columns each step touches, how
     many autonomous prox steps each must replay (Lemma 11), which slots
@@ -249,7 +253,7 @@ def _lazy_inner_loop(h_prime: Callable, reg: Regularizer, eta: float,
                              inner_batch=idx.shape[1])
     if not with_stats:
         return u
-    return u, _epoch_plan_stats(eplan)
+    return u, _epoch_plan_stats(eplan, idx.shape[1])
 
 
 def _lazy_inner_loop_enc(h_prime: Callable, reg: Regularizer, eta: float,
@@ -289,14 +293,17 @@ def _lazy_inner_loop_enc(h_prime: Callable, reg: Regularizer, eta: float,
                              inner_batch=idx.shape[1])
     if not with_stats:
         return u
-    return u, _epoch_plan_stats(eplan)
+    return u, _epoch_plan_stats(eplan, idx.shape[1])
 
 
-def _epoch_plan_stats(eplan) -> Array:
-    """(catch_up, prox_skip) for one epoch, read off the gather plan."""
+def _epoch_plan_stats(eplan, inner_batch: int) -> Array:
+    """(catch_up, prox_skip, dup_fold) for one epoch, read off the
+    gather plan."""
     with jax.named_scope(SCOPE_PLAN):
+        fold = plan_mod.fold_bounds(eplan.rep, inner_batch)
         return jnp.stack([jnp.sum(eplan.q.astype(jnp.float32)),
-                          jnp.sum(eplan.qf.astype(jnp.float32))])
+                          jnp.sum(eplan.qf.astype(jnp.float32)),
+                          jnp.sum(fold.astype(jnp.float32))])
 
 
 def _lazy_inner_loop_ref(h_prime: Callable, reg: Regularizer, eta: float,
@@ -527,7 +534,7 @@ def _outer_step_lazy_core(obj: Objective, reg: Regularizer,
     # --- phase 3: cooperative averaging -----------------------------------
     ctr = state.ctr
     if want_stats:
-        u_final, stats_w = out          # stats_w: (p, 2) per-worker sums
+        u_final, stats_w = out          # stats_w: (p, 3) per-worker sums
         with jax.named_scope(SCOPE_PLAN):
             ctr = ctr + jnp.sum(stats_w, axis=0)
     else:

@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import plan as _plan
 from repro.kernels import ref as _ref
 from repro.kernels.lazy_prox import lazy_prox_pallas
 from repro.kernels.fused_prox_svrg import (fused_prox_svrg_pallas,
@@ -136,7 +137,7 @@ def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
     M, S = plan.cflat.shape
     b = inner_batch
     k = S // b
-    kp = -(-k // _LANES) * _LANES
+    kp = _plan.padded_slot_width(k)
     padw = kp - k
 
     def pad_slots(a, fill, dtype):
@@ -157,6 +158,8 @@ def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
                  + jax.lax.broadcasted_iota(jnp.int32, (M, b, kp), 1) * kp)
     pad_mask = jax.lax.broadcasted_iota(jnp.int32, (M, b, kp), 2) >= k
     rep_p = jnp.where(pad_mask, slot_iota, rep_padded).reshape(M, 1, -1)
+    # the fold runs only up to each step's last duplicate slot
+    fold_n = _plan.fold_bounds(plan.rep, b).reshape(M, 1, 1)
     # encoded shards deliver vb as uint16 bf16 bits (plan.EpochGathers);
     # pad in the native dtype and let the kernel decode in VMEM —
     # padding bits 0x0000 decode to exactly 0.0f, same as f32 padding
@@ -167,10 +170,10 @@ def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
     out = fused_lazy_epoch_pallas(
         _tiles_with_spare(u0, d, jnp.float32),
         _tiles_with_spare(z, d, jnp.float32),
-        _tiles_with_spare(plan.qf, d, jnp.int32), cflat_p, q_p, rep_p, vb_p,
-        gathers.yb.reshape(M, b, 1).astype(jnp.float32), zg_p,
+        _tiles_with_spare(plan.qf, d, jnp.int32), cflat_p, q_p, rep_p,
+        fold_n, vb_p, gathers.yb.reshape(M, b, 1).astype(jnp.float32), zg_p,
         gathers.sw.reshape(M, b, 1).astype(jnp.float32), h_prime=h_prime,
-        eta=eta, eta_eff=eta_eff, lam1=lam1, lam2=lam2,
+        eta=eta, eta_eff=eta_eff, lam1=lam1, lam2=lam2, n_cols=k,
         interpret=_interpret())
     return out.reshape(-1)[:d].astype(u0.dtype)
 

@@ -21,7 +21,8 @@ slot.  Plan rows are padded to a 128-multiple slot count per sample
 with a dummy column pointing at that spare slot (value 0, staleness 0),
 whose update is the identity.  The per-step vector operands arrive as
 (b, kp) blocks (samples x padded slots); the active columns and
-duplicate representatives arrive in SMEM, one scalar per slot.
+duplicate representatives arrive in SMEM, one scalar per slot, beside
+the step's fold bound.
 
 Mosaic lowers no vector gather or scatter, so the step moves the
 touched coordinates slot by slot: a scalar column index from SMEM
@@ -29,6 +30,13 @@ selects an (8, 128) tile by its leading index, and a lane mask picks
 or replaces the one coordinate in it.  Duplicate columns of a step are
 folded into their representative slot in slot order (the reference's
 scatter-add order), and only representatives are written back.
+
+The slot loops run only over slots that can change the iterate: the
+gather and the scatter over the b * k real slots (a padded slot's
+gather reads 0 and its write-back stores 0, which the zero-initialised
+gather block and the untouched spare coordinate already hold), and the
+fold up to the step's bound, one past its last duplicate slot
+(`core.plan.fold_bounds`; every later slot represents itself).
 """
 from __future__ import annotations
 
@@ -54,19 +62,26 @@ def _pick(x, mask):
                    axis=0, keepdims=True)
 
 
-def _epoch_kernel(cf_ref, rep_ref, u0_ref, z_ref, qf_ref, q_ref, vb_ref,
-                  yb_ref, zg_ref, sw_ref, o_ref, *, h_prime, eta, eta_eff,
-                  lam1, lam2, n_steps):
+def _epoch_kernel(cf_ref, rep_ref, nf_ref, u0_ref, z_ref, qf_ref, q_ref,
+                  vb_ref, yb_ref, zg_ref, sw_ref, o_ref, *, h_prime, eta,
+                  eta_eff, lam1, lam2, n_cols, n_steps):
     i = pl.program_id(0)
     n_tiles = o_ref.shape[0]
     b, kp = q_ref.shape
-    n_slots = b * kp
 
     def over_tiles(body):
         def step(t, carry):
             body(t)
             return carry
         jax.lax.fori_loop(0, n_tiles, step, 0)
+
+    def over_real_slots(body, carry):
+        # slot i of sample j sits at j * kp + i; slots i >= n_cols are
+        # padding
+        def sample(j, carry):
+            return jax.lax.fori_loop(0, n_cols,
+                                     lambda i, c: body(j * kp + i, c), carry)
+        return jax.lax.fori_loop(0, b, sample, carry)
 
     @pl.when(i == 0)
     def _init():
@@ -85,8 +100,7 @@ def _epoch_kernel(cf_ref, rep_ref, u0_ref, z_ref, qf_ref, q_ref, vb_ref,
         val = _pick(o_ref[c >> _TILE_BITS], pos == (c & _TILE_MASK))
         return jnp.where(slot == s, val, g)
 
-    u_g = jax.lax.fori_loop(0, n_slots, gather,
-                            jnp.zeros((b, kp), jnp.float32))
+    u_g = over_real_slots(gather, jnp.zeros((b, kp), jnp.float32))
 
     # 2. Lemma-11 catch-up, then the support-restricted VR gradient
     #    entries (anchor half precomputed)
@@ -103,13 +117,14 @@ def _epoch_kernel(cf_ref, rep_ref, u0_ref, z_ref, qf_ref, q_ref, vb_ref,
     ge = coef * vbm
 
     # duplicate-safe accumulation: fold every duplicate slot's entry
-    # into its representative (rep[s] <= s), in slot order
+    # into its representative (rep[s] <= s), in slot order, up to the
+    # step's last duplicate slot
     def fold(s, ge):
         r = rep_ref[0, s]
         src = jnp.where(r == s, -1, s)
         return jnp.where(slot == r, ge + _pick(ge, slot == src), ge)
 
-    ge = jax.lax.fori_loop(0, n_slots, fold, ge)
+    ge = jax.lax.fori_loop(0, nf_ref[0, 0], fold, ge)
 
     # 3. eta-step + elastic-net prox, written back by representatives
     new = prox_elastic_net(u_t - eta * (zgm + ge), eta, lam1, lam2)
@@ -123,7 +138,7 @@ def _epoch_kernel(cf_ref, rep_ref, u0_ref, z_ref, qf_ref, q_ref, vb_ref,
                                  _pick(new, slot == s), o_ref[t])
         return carry
 
-    jax.lax.fori_loop(0, n_slots, scatter, 0)
+    over_real_slots(scatter, 0)
 
     @pl.when(i == n_steps - 1)
     def _final_catch_up():
@@ -134,42 +149,49 @@ def _epoch_kernel(cf_ref, rep_ref, u0_ref, z_ref, qf_ref, q_ref, vb_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("h_prime", "eta", "eta_eff",
-                                             "lam1", "lam2", "interpret"))
+                                             "lam1", "lam2", "n_cols",
+                                             "interpret"))
 def fused_lazy_epoch_pallas(u0_t: jax.Array, z_t: jax.Array, qf_t: jax.Array,
                             cflat: jax.Array, q: jax.Array, rep: jax.Array,
-                            vb: jax.Array, yb: jax.Array, zg: jax.Array,
-                            sw: jax.Array, *, h_prime, eta: float,
-                            eta_eff: float, lam1: float, lam2: float,
+                            fold_n: jax.Array, vb: jax.Array, yb: jax.Array,
+                            zg: jax.Array, sw: jax.Array, *, h_prime,
+                            eta: float, eta_eff: float, lam1: float,
+                            lam2: float, n_cols: int,
                             interpret: bool = True) -> jax.Array:
     """u0_t/z_t: (T, 8, 128) f32; qf_t: (T, 8, 128) i32.
 
-    Plan operands per step m, with kp a 128-multiple: q/zg (M, b, kp),
-    yb/sw (M, b, 1), cflat/rep (M, 1, b * kp) int32 (rep in padded
-    slot space).  `vb` is (M, b, kp) f32, or uint16 bf16 bit patterns
-    from an encoded shard — decoded in VMEM, so the per-step value
-    traffic from HBM halves.
+    Plan operands per step m, with kp a 128-multiple holding each
+    sample's `n_cols` real slots first: q/zg (M, b, kp), yb/sw
+    (M, b, 1), cflat/rep (M, 1, b * kp) int32 (rep in padded slot
+    space), fold_n (M, 1, 1) int32 (`core.plan.fold_bounds`).  `vb` is
+    (M, b, kp) f32, or uint16 bf16 bit patterns from an encoded shard —
+    decoded in VMEM, so the per-step value traffic from HBM halves.
     """
     T, sub, lanes = u0_t.shape
     M, b, kp = q.shape
     assert (sub, lanes) == (8, _LANES) and kp % _LANES == 0, (u0_t.shape, kp)
+    assert 0 < n_cols <= kp, (n_cols, kp)
     assert cflat.shape == rep.shape == (M, 1, b * kp), (cflat.shape,
                                                         rep.shape)
+    assert fold_n.shape == (M, 1, 1), fold_n.shape
     assert vb.dtype in (jnp.float32, jnp.uint16), vb.dtype
     full = pl.BlockSpec((T, 8, _LANES), lambda i: (0, 0, 0))
     row_s = pl.BlockSpec((None, b, kp), lambda i: (i, 0, 0))
     row_b = pl.BlockSpec((None, b, 1), lambda i: (i, 0, 0))
     slots = pl.BlockSpec((None, 1, b * kp), lambda i: (i, 0, 0),
                          memory_space=pltpu.SMEM)
+    step_scalar = pl.BlockSpec((None, 1, 1), lambda i: (i, 0, 0),
+                               memory_space=pltpu.SMEM)
     kernel = functools.partial(_epoch_kernel, h_prime=h_prime, eta=eta,
                                eta_eff=eta_eff, lam1=lam1, lam2=lam2,
-                               n_steps=M)
+                               n_cols=n_cols, n_steps=M)
     return pl.pallas_call(
         kernel,
         grid=(M,),
-        in_specs=[slots, slots, full, full, full, row_s, row_s, row_b,
-                  row_s, row_b],
+        in_specs=[slots, slots, step_scalar, full, full, full, row_s, row_s,
+                  row_b, row_s, row_b],
         out_specs=full,
         out_shape=jax.ShapeDtypeStruct(u0_t.shape, u0_t.dtype),
         interpret=interpret,
         name="fused_lazy_epoch",
-    )(cflat, rep, u0_t, z_t, qf_t, q, vb, yb, zg, sw)
+    )(cflat, rep, fold_n, u0_t, z_t, qf_t, q, vb, yb, zg, sw)

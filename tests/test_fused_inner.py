@@ -254,6 +254,112 @@ def test_pallas_epoch_kernel_bf16_vals(monkeypatch, b):
                                atol=5e-6, rtol=1e-4)
 
 
+def _np_fold_bounds(rep, b):
+    """Per step, one past the last slot s with rep[s] != s, in the
+    kernel's padded slot space (k rounded up to 128 lanes); 0 if none."""
+    rep = np.asarray(rep)
+    M, S = rep.shape
+    k = S // b
+    kp = -(-k // 128) * 128
+    out = np.zeros(M, np.int64)
+    for m in range(M):
+        dup = [s for s in range(S) if rep[m, s] != s]
+        if dup:
+            out[m] = dup[-1] // k * kp + dup[-1] % k + 1
+    return out
+
+
+def _run_kernel_vs_jnp(monkeypatch, vals, cols, idx, seed):
+    rng = np.random.RandomState(seed)
+    n_k, d = cols.shape[0], int(np.max(np.asarray(cols))) + 5
+    y = jnp.asarray(np.sign(rng.randn(n_k)).astype(np.float32))
+    w = jnp.asarray(rng.randn(d).astype(np.float32) * 0.1)
+    z = jnp.asarray(rng.randn(d).astype(np.float32) * 0.02)
+    run = lambda: _lazy_inner_loop(logistic_h_prime, Regularizer(1e-2, 1e-3),
+                                   0.3, w, w, z, vals, cols, y, idx)
+    ref = run()
+    monkeypatch.setenv("USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_SPARSE_INNER_KERNEL", "1")
+    np.testing.assert_allclose(np.asarray(run()), np.asarray(ref),
+                               atol=5e-6, rtol=1e-4)
+
+
+def test_pallas_epoch_kernel_full_lane_rows(monkeypatch):
+    """k = 128: no padded slots, so the gather and scatter loops run
+    over every slot of the lane width."""
+    rng = np.random.RandomState(11)
+    vals, cols = _random_shard(rng, 6, 300, 128)
+    assert plan_mod.padded_slot_width(128) == 128
+    idx = jnp.asarray(rng.randint(0, 6, size=(6, 1)), jnp.int32)
+    _run_kernel_vs_jnp(monkeypatch, vals, cols, idx, seed=12)
+
+
+def _some_duplicate_rows(rng, k=9):
+    """12 rows over disjoint column ranges: rows 0-5 distinct, row 6
+    repeats a column in its last slot, row 7 in slot 3, rows 8-11
+    repeat a random slot."""
+    n_k = 12
+    cols = np.stack([r * k + rng.permutation(k) for r in range(n_k)])
+    cols[6, k - 1] = cols[6, 2]
+    cols[7, 3] = cols[7, 0]
+    for r in range(8, n_k):
+        src, dst = rng.choice(k, 2, replace=False)
+        cols[r, dst] = cols[r, src]
+    vals = rng.randn(n_k, k).astype(np.float32)
+    return jnp.asarray(vals), jnp.asarray(cols.astype(np.int32))
+
+
+@pytest.mark.parametrize("b", [1, 3])
+def test_pallas_epoch_kernel_varying_fold_bound(monkeypatch, b):
+    """Duplicates in only some rows, one in a step's last real slot: the
+    fold bound varies from step to step and is 0 for some steps."""
+    rng = np.random.RandomState(21 + b)
+    k = 9
+    vals, cols = _some_duplicate_rows(rng, k)
+    M = 20
+    idx = rng.randint(0, 12, size=(M, b))
+    # distinct rows (bound 0), the last-slot duplicate last in its step
+    # (the largest bound), and a duplicate early in the step
+    idx[0] = np.arange(b)
+    idx[1, :-1], idx[1, -1] = np.arange(1, b), 6
+    idx[2, 0], idx[2, 1:] = 7, np.arange(1, b)
+    idx = jnp.asarray(idx, jnp.int32)
+    eplan = plan_mod.build_epoch_plan(cols, idx, int(np.max(cols)) + 5)
+    bounds = np.asarray(plan_mod.fold_bounds(eplan.rep, b))
+    assert bounds[0] == 0
+    assert bounds[1] == (b - 1) * 128 + k
+    assert bounds[2] == 4
+    assert len(set(bounds.tolist())) > 2
+    _run_kernel_vs_jnp(monkeypatch, vals, cols, idx, seed=b)
+
+
+@pytest.mark.parametrize("b", [1, 3])
+def test_dup_fold_counter_counts_fold_bounds(b):
+    """The epoch's `dup_fold` stat is the NumPy count of the fold bounds
+    over the plan's representatives, and 0 where rows repeat no column."""
+    rng = np.random.RandomState(31 + b)
+    n_k, d, k, M = 12, 97, 9, 20
+    vals, cols = _random_shard(rng, n_k, d, k)
+    y = jnp.asarray(np.sign(rng.randn(n_k)).astype(np.float32))
+    w = jnp.asarray(rng.randn(d).astype(np.float32) * 0.1)
+    idx = jnp.asarray(rng.randint(0, n_k, size=(M, b)), jnp.int32)
+    stats = lambda v, c: _lazy_inner_loop(
+        logistic_h_prime, Regularizer(1e-2, 1e-3), 0.3, w, w, w * 0.1, v,
+        c, y, idx, with_stats=True)[1]
+    _, _, rep, _ = _brute_plan(cols, idx, d)
+    want = _np_fold_bounds(rep, b).sum()
+    assert want > 0
+    assert float(stats(vals, cols)[2]) == want
+    # no row repeats a column: only a step that samples two rows with a
+    # column in common folds, so b = 1 reads 0
+    distinct = jnp.asarray(
+        np.arange(n_k * k, dtype=np.int32).reshape(n_k, k) % d)
+    _, _, rep, _ = _brute_plan(distinct, idx, d)
+    want = _np_fold_bounds(rep, b).sum()
+    assert float(stats(vals, distinct)[2]) == want
+    assert (want == 0) == (b == 1)
+
+
 def test_use_pallas_modes_agree(monkeypatch):
     """USE_PALLAS=0 (pure jnp) and =1 produce the same fused trajectory."""
     reg = Regularizer(1e-3, 1e-3)

@@ -4,7 +4,8 @@ The TPU compiler is installed even where no chip is attached: a kernel
 that Mosaic refuses (an unaligned block, an op with no lowering, too
 much VMEM) fails here instead of on the chip.  The shapes are the
 LIBSVM rcv1 width, d = 47,236 (376 x 128 tiles), and one epoch plan of
-M = 2,530 steps x 128 slots.  Nothing runs; each test only compiles.
+M = 2,530 steps x 128 slots, 74 of them real, with a fold bound a step.
+Nothing runs; each test only compiles.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and the test
@@ -27,6 +28,7 @@ D = 47_236
 ROWS = 376                  # ceil(D / 128), rounded up to a multiple of 8
 TILES = -(-(D + 1) // 1024)  # (8, 128) tiles with a spare tail slot
 M, B, KP = 2_530, 1, 128    # steps x samples x padded slots per sample
+K = 74                      # rcv1's nonzeros a row: the real slots
 KW = dict(eta=2.0, lam1=1e-4, lam2=1e-4, interpret=False)
 
 
@@ -76,13 +78,15 @@ def test_fused_lazy_epoch_compiles_at_rcv1_width(spec, vals):
     vb = spec((M, B, KP), jnp.float32 if vals == "f32" else jnp.uint16)
     per_sample = spec((M, B, 1), jnp.float32)
 
+    fold_n = spec((M, 1, 1), jnp.int32)
+
     def epoch(*a):
         return fused_lazy_epoch_pallas(
             *a, h_prime=logistic_h_prime, eta=2.0,
-            eta_eff=2.0 / (1.0 + 2e-4), lam1=1e-4, lam2=1e-4,
+            eta_eff=2.0 / (1.0 + 2e-4), lam1=1e-4, lam2=1e-4, n_cols=K,
             interpret=False)
 
     _assert_kernel(epoch, tile_f32, tile_f32,
                    spec((TILES, 8, 128), jnp.int32), slots,
-                   spec((M, B, KP), jnp.int32), slots, vb, per_sample,
-                   step_f32, per_sample)
+                   spec((M, B, KP), jnp.int32), slots, fold_n, vb,
+                   per_sample, step_f32, per_sample)
