@@ -1007,7 +1007,8 @@ def _prepare_distributed(obj: Objective, reg: Regularizer, X, y,
     statics = None
     if cfg.inner_path == "lazy":
         p = mesh.shape[axis]
-        statics = _distributed_statics(cfg, mesh, axis, X, p)
+        with obs.span("mesh.statics"):
+            statics = _distributed_statics(cfg, mesh, axis, X, p)
     return cfg, X, statics
 
 
@@ -1052,8 +1053,9 @@ def run_distributed_scanned(obj: Objective, reg: Regularizer, X, y: Array,
     trajectory's tail from the same iterate.
 
     Returns (w_T, values, nnz) as numpy arrays of T // record_every + 1
-    entries.  Its host spans are `mesh.prepare` (the sharded statics and
-    the inputs), `mesh.dispatch` and `mesh.fetch`, as in `run_scanned`.
+    entries.  Its host spans are `mesh.prepare` (the sharded statics, in
+    its `mesh.statics`, and the inputs), `mesh.dispatch` and
+    `mesh.fetch`, as in `run_scanned`.
     """
     with obs.span("mesh.prepare"):
         cfg, X, statics = _prepare_distributed(obj, reg, X, y, cfg, mesh,
