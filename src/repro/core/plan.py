@@ -22,11 +22,12 @@ Two plan builders, selected by shard shape:
   (M, n_k) cummax plus one fused (M, k, n_k) masked reduction.  No
   sort anywhere.
 * **sort-based** (the general path, any b): pack (col, step) into one
-  int32 key, single-operand ``jnp.sort`` it, and recover each entry's
-  group head with ``jnp.searchsorted`` — the predecessor of a group
-  head in sorted order is exactly the latest earlier touch of the same
-  column.  (A variadic ``argsort`` is ~5x slower than a single-key
-  sort under XLA CPU, which is why the key is packed.)
+  int32 key, add one probe key per column that sorts after its last
+  touch, and sort the keys with their positions.  The neighbour just
+  before a group head in sorted order is the latest earlier touch of
+  the same column, so a shifted compare finds every ``last``, a
+  running max copies it to the group's duplicates, and a sort by
+  position puts the plan back in step order.  No binary search.
 
 Both produce identical plans (tests/test_fused_inner.py enforces it
 against a literal Python replay).
@@ -217,58 +218,51 @@ def _plan_from_membership(cols_k: Array, idx: Array, d: int,
 
 
 def _plan_from_sort(cols_k: Array, idx: Array, d: int) -> EpochPlan:
-    """General path: one packed-key sort + searchsorted, any b.
+    """General path, any b: a sort by key, neighbour compares in sorted
+    order, and a sort back by position.
 
-    The packed key col * M + step must fit int32, i.e. d * M < 2^31 —
-    at the paper's scales (d <= 2^18, M <= 2^12) this always holds;
-    an assertion guards the boundary.
+    The N = M * S touches are keyed col * (M + 1) + step, and one probe
+    per column j is keyed j * (M + 1) + M, so that it sorts just after
+    column j's last touch.  The keys are sorted together with each
+    entry's flat position.  A group head (the first entry of a key) is
+    preceded in sorted order by the latest earlier touch of its column,
+    if any: that gives ``last``, and q = step - last, which for a
+    probe (step M) is the column's final staleness qf.  A running max
+    carries each head's ``last`` and slot to the group's duplicates,
+    and a second sort, keyed on the position, puts every entry back in
+    place (on a TPU it is faster than the scatter by position).  The
+    packed values must fit int32: the key and the carried column and
+    ``last`` (d * (M + 1)), and the carried sorted index and slot
+    ((N + d) * S).  At the paper's scales (d <= 2^18, M <= 2^12,
+    N * S <= 2^28) they do; a ValueError guards the boundary.
     """
     M, b = idx.shape
     k = cols_k.shape[-1]
     S = b * k
-    assert d * M < (1 << 31), (
-        f"packed plan key overflows int32 for d={d}, M={M}")
-    cflat = jnp.take(cols_k, idx, axis=0).reshape(M, S)
     N = M * S
-    col = cflat.reshape(-1)
-    step = jax.lax.broadcasted_iota(jnp.int32, (M, S), 0).reshape(-1)
-    key = col * M + step                     # unique per (col, step) group
-    skey = jnp.sort(key)
-    # one searchsorted serves both deliveries: group heads for the N
-    # touch entries, and (when cheap enough, see below) the run-end
-    # probe for all d final-staleness counts
-    qf_by_search = d <= 4 * N
-    if qf_by_search:
-        jq = (jnp.arange(d, dtype=jnp.int32) + 1) * M
-        pos_all = jnp.searchsorted(skey, jnp.concatenate([key, jq]),
-                                   side="left").astype(jnp.int32)
-        pos, qpos = pos_all[:N], pos_all[N:]
-    else:
-        pos = jnp.searchsorted(skey, key, side="left").astype(jnp.int32)
-    # the entry just before a group head is the latest earlier touch of
-    # the same column (duplicates inside a group share the key)
-    prev_key = jnp.take(skey, jnp.maximum(pos - 1, 0))
-    same_col = (prev_key // M == col) & (pos > 0)
-    last = jnp.where(same_col, prev_key % M + 1, 0)
-    q = (step - last).reshape(M, S)
-    # duplicate representative: smallest slot of each (col, step) group
-    slot = jax.lax.broadcasted_iota(jnp.int32, (M, S), 1).reshape(-1)
-    rep = jnp.take(jnp.full((N,), S, jnp.int32).at[pos].min(slot),
-                   pos).reshape(M, S)
-    # final staleness per coordinate: two exact delivery schemes behind
-    # the static size switch above.  When the touch count N is
-    # comparable to d, the scatter-free vectorized binary search wins
-    # (the last entry of coordinate j's run in sorted order sits just
-    # before the first key >= (j+1)*M); when N << d, XLA's serial
-    # scatter-max over the N touches beats paying d binary searches.
-    if qf_by_search:
-        j = jnp.arange(d, dtype=jnp.int32)
-        prevj = jnp.take(skey, jnp.maximum(qpos - 1, 0))
-        hit = (qpos > 0) & (prevj // M == j)
-        last_final = jnp.where(hit, prevj % M + 1, 0)
-    else:
-        last_final = jnp.zeros((d,), jnp.int32).at[col].max(step + 1)
-    return EpochPlan(cflat=cflat, q=q, rep=rep, qf=M - last_final)
+    if max(d * (M + 1), (N + d) * S) >= (1 << 31):
+        raise ValueError(
+            f"packed plan values overflow int32 for d={d}, M={M}, S={S}")
+    cflat = jnp.take(cols_k, idx, axis=0).reshape(M, S)
+    flat = jnp.arange(N + d, dtype=jnp.int32)   # step * S + slot, then N + j
+    col = jnp.concatenate([cflat.reshape(-1), jnp.arange(d, dtype=jnp.int32)])
+    step = jnp.where(flat < N, flat // S, M)
+    # stable: a (col, step) group keeps its slots in order, so its head
+    # holds the smallest slot, the duplicate representative
+    skey, spos = jax.lax.sort((col * (M + 1) + step, flat), num_keys=1,
+                              is_stable=True)
+    scol, sstep = skey // (M + 1), skey % (M + 1)
+    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), skey[:-1]])
+    head = skey != prev
+    # heads' (col, last) and (sorted index, slot) both rise along the
+    # sorted order, so a running max hands each head's to its group
+    last_at_head = jnp.where(prev // (M + 1) == scol, prev % (M + 1) + 1, 0)
+    last = jax.lax.cummax(jnp.where(head, scol * (M + 1) + last_at_head,
+                                    0)) - scol * (M + 1)
+    rep = jax.lax.cummax(jnp.where(head, flat * S + spos % S, 0)) % S
+    _, packed = jax.lax.sort((spos, (sstep - last) * S + rep), num_keys=1)
+    return EpochPlan(cflat=cflat, q=(packed[:N] // S).reshape(M, S),
+                     rep=(packed[:N] % S).reshape(M, S), qf=packed[N:] // S)
 
 
 # ---------------------------------------------------------------------------

@@ -72,13 +72,38 @@ def _random_shard(rng, n_k, d, k, dup_frac=0.3):
     return jnp.asarray(vals), jnp.asarray(cols)
 
 
-@pytest.mark.parametrize("b", [1, 3])
-@pytest.mark.parametrize("builder", ["membership", "sort"])
-def test_epoch_plan_matches_replay(b, builder):
-    rng = np.random.RandomState(0)
+def _plan_case(case, b, rng):
+    """(vals, cols, idx, d) of one replay case: a random shard with
+    forced duplicates, or an edge of the sorted-order bookkeeping."""
     n_k, d, k, M = 12, 97, 9, 20
+    if case == "m1":
+        M = 1
     vals, cols = _random_shard(rng, n_k, d, k)
-    idx = jnp.asarray(rng.randint(0, n_k, size=(M, b)), jnp.int32)
+    cols = np.asarray(cols).copy()
+    idx = rng.randint(0, n_k, size=(M, b)).astype(np.int32)
+    if case == "every_step":        # one column in every row
+        cols[:, 3] = 41
+    elif case == "one_col_row":     # a row of k copies of one column,
+        cols[5] = 17                # sampled first, mid-epoch and last
+        idx[[0, M // 2, M - 1], 0] = 5
+    elif case == "untouched":       # columns >= d // 2 are never touched
+        cols %= d // 2
+    return vals, jnp.asarray(cols), jnp.asarray(idx), d
+
+
+PLAN_CASES = ["random", "every_step", "one_col_row", "untouched", "m1"]
+
+
+@pytest.mark.parametrize("builder,b,case", [
+    pytest.param(builder, b, case,
+                 id=f"{builder}-{b}" + ("" if case == "random"
+                                        else f"-{case}"))
+    for builder in ("membership", "sort") for b in (1, 3)
+    for case in PLAN_CASES
+    if case == "random" or builder == "sort" or b == 1])
+def test_epoch_plan_matches_replay(builder, b, case):
+    rng = np.random.RandomState(0)
+    vals, cols, idx, d = _plan_case(case, b, rng)
     if builder == "membership":
         if b != 1:
             pytest.skip("membership builder is b = 1 only")
@@ -92,6 +117,31 @@ def test_epoch_plan_matches_replay(b, builder):
     np.testing.assert_array_equal(np.asarray(eplan.q), q)
     np.testing.assert_array_equal(np.asarray(eplan.rep), rep)
     np.testing.assert_array_equal(np.asarray(eplan.qf), qf)
+    if case == "untouched":
+        assert (qf[d // 2:] == idx.shape[0]).all()
+
+
+@pytest.mark.parametrize("bound", ["col_last", "slot"])
+def test_sort_plan_packing_bound(bound):
+    """The sort plan traces at the largest shape its int32 packings
+    admit and refuses the next: d * (M + 1) for the key and the carried
+    column and last, (N + d) * S for the carried sorted index and
+    slot."""
+    if bound == "col_last":     # (M, k, d): d * (M + 1) = 2^31 - 8, 2^31
+        fits, past = (7, 1, (1 << 28) - 1), (7, 1, 1 << 28)
+    else:                       # (N + d) * S, N = M * k, S = k = 2^10:
+        k, d = 1 << 10, 96      # 2^31 - 2^20 + 96 * 2^10, then past 2^31
+        fits, past = ((1 << 11) - 1, k, d), (1 << 11, k, d)
+
+    def plan(M, k, d):
+        return jax.eval_shape(
+            lambda c, i: plan_mod._plan_from_sort(c, i, d),
+            jax.ShapeDtypeStruct((4, k), jnp.int32),
+            jax.ShapeDtypeStruct((M, 1), jnp.int32))
+
+    assert plan(*fits).qf.shape == (fits[2],)
+    with pytest.raises(ValueError, match="overflow int32"):
+        plan(*past)
 
 
 def test_build_epoch_plan_dispatch_equivalence():

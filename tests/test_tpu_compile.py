@@ -5,6 +5,7 @@ that Mosaic refuses (an unaligned block, an op with no lowering, too
 much VMEM) fails here instead of on the chip.  The shapes are the
 LIBSVM rcv1 width, d = 47,236 (376 x 128 tiles), and one epoch plan of
 M = 2,530 steps x 128 slots, 74 of them real, with a fold bound a step.
+The epoch-plan build is compiled at the benchmark cells' shapes too.
 Nothing runs; each test only compiles.
 
 The topology is described inside a module fixture, never at import:
@@ -12,12 +13,14 @@ only one process may load the TPU library at a time, and the test
 workers all import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.plan import build_epoch_plan
 from repro.core.svrg import logistic_h_prime
 from repro.kernels.fused_prox_svrg import (fused_prox_svrg_diff_pallas,
                                            fused_prox_svrg_pallas)
@@ -90,3 +93,21 @@ def test_fused_lazy_epoch_compiles_at_rcv1_width(spec, vals):
                    spec((TILES, 8, 128), jnp.int32), slots,
                    spec((M, B, KP), jnp.int32), slots, fold_n, vb,
                    per_sample, step_f32, per_sample)
+
+
+@pytest.mark.parametrize("workers,rows", [(8, 2_530), (None, 5_060)],
+                         ids=["rcv1.uniform", "rcv1-mesh4.uniform"])
+def test_epoch_plan_compiles_without_a_loop(spec, workers, rows):
+    """The sort-based plan at the cells' shapes (8 workers vmapped on
+    one chip; one worker of the four-chip mesh) is straight-line code:
+    a sort by key and one by position, no `while` of a binary search
+    and no scatter."""
+    lead = () if workers is None else (workers,)
+    plan = lambda c, i: build_epoch_plan(c, i, D)  # noqa: E731
+    if workers is not None:
+        plan = jax.vmap(plan)
+    text = jax.jit(plan).lower(spec(lead + (rows, K), jnp.int32),
+                               spec(lead + (rows, 1), jnp.int32)
+                               ).compile().as_text()
+    assert not re.search(r" (while|scatter)\(", text)
+    assert len(re.findall(r" sort\(", text)) == 2
