@@ -1,9 +1,7 @@
-"""Roofline report: reads the dry-run artifacts (results/dryrun/*.json)
-AND the cross-PR perf trails (BENCH_*.json at the repo root) and emits
-summary CSV rows plus %-of-peak markdown tables (EXPERIMENTS.md
-section Roofline).
+"""Roofline report: reads the cross-PR perf trails (BENCH_*.json at the
+repo root) and emits summary CSV rows plus a %-of-peak markdown table.
 
-The perf-trail half keys on the bench-rows/v2 schema written by
+It keys on the bench-rows/v2 schema written by
 ``benchmarks/run.py --json``: each row's `pct_peak` (modeled traffic
 vs the measured host roofline, see repro.obs.roofline) and the file's
 `host` fingerprint."""
@@ -15,14 +13,6 @@ import os
 from typing import Dict, List
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def load(directory="results/dryrun") -> List[dict]:
-    out = []
-    for f in sorted(glob.glob(os.path.join(ROOT, directory, "*.json"))):
-        with open(f) as fh:
-            out.append(json.load(fh))
-    return out
 
 
 def load_bench(pattern="BENCH_*.json") -> List[dict]:
@@ -60,54 +50,7 @@ def _trail_summary(doc: dict) -> Dict:
 
 
 def main() -> List[Dict]:
-    rows = [_trail_summary(doc) for doc in load_bench()]
-    for r in load():
-        if r.get("status") != "ok":
-            rows.append({"name": f"dryrun/{r['arch']}/{r['shape']}/"
-                                 f"{r['mesh']}/{r['step']}",
-                         "us_per_call": "",
-                         "derived": f"status={r.get('status')}"})
-            continue
-        terms = r["roofline"]
-        rows.append({
-            "name": (f"dryrun/{r['arch']}/{r['shape']}/{r['mesh']}/"
-                     f"{r['step']}"),
-            "us_per_call": f"{r.get('compile_s', 0) * 1e6:.0f}",
-            "derived": (
-                f"fits={r['fits_hbm']};bottleneck={terms['bottleneck']};"
-                f"t_comp={terms['t_compute']:.3g};"
-                f"t_mem={terms['t_memory']:.3g};"
-                f"t_coll={terms['t_collective']:.3g};"
-                f"useful={r.get('useful_ratio') or 0:.3f}"),
-        })
-    return rows
-
-
-def markdown_table(directory="results/dryrun") -> str:
-    lines = [
-        "| arch | shape | mesh | step | fits | t_comp (s) | t_mem (s) | "
-        "t_coll (s) | bottleneck | useful |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for r in load(directory):
-        if r.get("status") == "skipped":
-            lines.append(
-                f"| {r['arch']} | {r['shape']} | {r['mesh']} | {r['step']} "
-                f"| skip | — | — | — | {r.get('reason', '')[:40]} | — |")
-            continue
-        if r.get("status") != "ok":
-            lines.append(
-                f"| {r['arch']} | {r['shape']} | {r['mesh']} | {r['step']} "
-                f"| **{r.get('status')}** | — | — | — | — | — |")
-            continue
-        t = r["roofline"]
-        lines.append(
-            f"| {r['arch']} | {r['shape']} | {r['mesh']} | {r['step']} | "
-            f"{'yes' if r['fits_hbm'] else 'NO'} | "
-            f"{t['t_compute']:.3g} | {t['t_memory']:.3g} | "
-            f"{t['t_collective']:.3g} | {t['bottleneck'][2:]} | "
-            f"{(r.get('useful_ratio') or 0):.2f} |")
-    return "\n".join(lines)
+    return [_trail_summary(doc) for doc in load_bench()]
 
 
 def bench_markdown_table(pattern="BENCH_*.json") -> str:

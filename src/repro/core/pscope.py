@@ -15,7 +15,7 @@ Two execution modes:
     semantics identical to the distributed mode.
   * `make_distributed_outer_step` — shard_map over a real mesh axis;
     the inner scan contains no DP collectives (this is the paper's
-    communication structure and what the dry-run lowers).
+    communication structure, which tests audit in the compiled HLO).
 
 Inner-loop engines, selected by `PScopeConfig.inner_path`:
   * "dense" — the microbatch VR gradient and the prox touch all d

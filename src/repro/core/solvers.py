@@ -21,7 +21,7 @@ metrics recorder capturing, at each recorded round,
 
 plus, on request, the partition-goodness estimate gamma(pi; eps) of
 Definition 5 (via the batched `repro.partition.gamma_estimate`).
-Training loops, the benchmark figures, and the dry-run grid all consume
+The benchmark figures, the examples and the chip benchmark all consume
 the same Trace, so adding a solver (one `@register` block here) or a
 partition scenario (one `register_scheme` block in
 `repro.partition.schemes`) immediately shows up everywhere.
@@ -113,8 +113,8 @@ class Trace:
 
     def record(self, w, value: float, comm_increment: float = 0.0, *,
                nnz: Optional[int] = None) -> None:
-        """Append one round: iterate w (array or pytree — the DL train
-        loop passes whole param trees), objective value, communication
+        """Append one round: iterate w (array or pytree; NNZ counts
+        every leaf), objective value, communication
         rounds spent since the previous record.  Pass `nnz` to skip the
         device reduction when the caller already holds it (the scanned
         drivers record NNZ on device); `w` may then be None."""

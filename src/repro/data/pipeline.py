@@ -1,23 +1,14 @@
-"""Data pipeline: deterministic, shardable, restartable iterators.
+"""Worker-major padded-CSR shards for the sparse lazy-prox engine.
 
-Four consumers:
-  * ERM benchmarks — worker-major partitions from core/partition.py.
-  * The sparse lazy-prox engine — `csr_partition` builds worker-major
-    padded-CSR shards (the `core.pscope` lazy inner loop's data layout)
-    from a flat `CSRMatrix` + a (p, n_k) partition index array.
-  * The streaming ingestion subsystem (`repro.datasets`) — its mmap
-    shard store persists exactly this worker-major padded-CSR layout on
-    disk, so `ShardStore.csr_p` is a drop-in (zero-copy) producer for
-    every `csr_partition` consumer; see docs/data.md.
-  * LM training — `TokenDataset` (synthetic token streams at the target
-    vocab) + `ShardedBatchIterator` that yields globally-consistent
-    batches sharded over the DP axes, with a restore-from-step API for
-    checkpoint/restart (fault tolerance requirement).
+`csr_partition` builds the `core.pscope` lazy inner loop's data layout
+from a flat `CSRMatrix` + a (p, n_k) partition index array.  The
+streaming ingestion subsystem (`repro.datasets`) persists exactly this
+layout on disk, so `ShardStore.csr_p` is a drop-in (zero-copy)
+producer for every `csr_partition` consumer; see docs/data.md.
 """
 from __future__ import annotations
 
-import dataclasses
-from typing import Iterator, Optional, Tuple
+from typing import Tuple
 
 import numpy as np
 import jax
@@ -39,70 +30,3 @@ def csr_partition(csr: CSRMatrix, y, idx) -> Tuple[CSRMatrix, jax.Array]:
     idx = np.asarray(idx)
     return shard_rows(csr, idx), jnp.asarray(y)[idx]
 
-
-@dataclasses.dataclass
-class TokenDataset:
-    """Deterministic synthetic token stream (LCG-mixed), any vocab size.
-
-    Used for the LM examples and smoke tests; stands in for a tokenized
-    corpus.  `sample(step, batch, seq)` is a pure function of (seed,
-    step), so every restart reproduces the same batch sequence — the
-    property checkpoint/restart tests rely on.
-    """
-
-    vocab_size: int
-    seed: int = 0
-
-    def sample(self, step: int, batch: int, seq: int) -> np.ndarray:
-        # splitmix-style hash over (seed, step, position)
-        idx = np.arange(batch * (seq + 1), dtype=np.uint64).reshape(
-            batch, seq + 1)
-        z = (idx + np.uint64(self.seed) * np.uint64(0x9E3779B97F4A7C15)
-             + np.uint64(step + 1) * np.uint64(0xBF58476D1CE4E5B9))
-        z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
-        z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
-        z = z ^ (z >> np.uint64(31))
-        return (z % np.uint64(self.vocab_size)).astype(np.int32)
-
-    def batch(self, step: int, batch: int, seq: int
-              ) -> Tuple[np.ndarray, np.ndarray]:
-        toks = self.sample(step, batch, seq)
-        return toks[:, :-1], toks[:, 1:]
-
-
-class ShardedBatchIterator:
-    """Yields (tokens, labels) numpy batches; restartable at any step.
-
-    In a real multi-host deployment each host materializes only its
-    slice (host_id, num_hosts); on this single-host container the slice
-    is the whole batch.  Determinism across restarts and across host
-    counts (elastic resize) is by construction: batch content depends
-    only on the global step.
-    """
-
-    def __init__(self, dataset: TokenDataset, global_batch: int, seq: int,
-                 start_step: int = 0, host_id: int = 0, num_hosts: int = 1):
-        self.ds = dataset
-        self.global_batch = global_batch
-        self.seq = seq
-        self.step = start_step
-        self.host_id = host_id
-        self.num_hosts = num_hosts
-        assert global_batch % num_hosts == 0
-
-    def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
-        return self
-
-    def __next__(self) -> Tuple[np.ndarray, np.ndarray]:
-        toks, labels = self.ds.batch(self.step, self.global_batch, self.seq)
-        per_host = self.global_batch // self.num_hosts
-        lo = self.host_id * per_host
-        hi = lo + per_host
-        self.step += 1
-        return toks[lo:hi], labels[lo:hi]
-
-    def state(self) -> dict:
-        return {"step": self.step}
-
-    def restore(self, state: dict) -> None:
-        self.step = int(state["step"])

@@ -15,9 +15,8 @@ seeds).  Collisions inside one vector just sum — identical to the
 duplicate-column convention of `repro.data.sparse.CSRMatrix`, so
 hashed chunks flow through the shard store unchanged.
 
-The hash is a splitmix64 finalizer over (index, seed) — stateless,
-vectorized, and the same mixing family `data/pipeline.TokenDataset`
-already uses, so determinism across runs/hosts is by construction.
+The hash is a splitmix64 finalizer over (index, seed) — stateless and
+vectorized, so determinism across runs/hosts is by construction.
 """
 from __future__ import annotations
 

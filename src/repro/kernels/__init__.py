@@ -2,8 +2,7 @@
 
   lazy_prox       — Lemma-11 recovery catch-up (the paper's Section 6)
   fused_prox_svrg — fused VR-gradient + elastic-net prox inner update
-  flash_attention — blocked online-softmax attention (prefill/long ctx)
 """
-from repro.kernels.ops import lazy_prox, fused_prox_svrg, flash_attention
+from repro.kernels.ops import lazy_prox, fused_prox_svrg
 
-__all__ = ["lazy_prox", "fused_prox_svrg", "flash_attention"]
+__all__ = ["lazy_prox", "fused_prox_svrg"]

@@ -21,7 +21,6 @@ from repro.kernels.lazy_prox import lazy_prox_pallas
 from repro.kernels.fused_prox_svrg import (fused_prox_svrg_pallas,
                                            fused_prox_svrg_diff_pallas)
 from repro.kernels.sparse_inner import fused_lazy_epoch_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 
 _LANES = 128
 
@@ -177,13 +176,3 @@ def fused_lazy_epoch(u0: jax.Array, z: jax.Array, plan, gathers, *, h_prime,
         interpret=_interpret())
     return out.reshape(-1)[:d].astype(u0.dtype)
 
-
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, scale: float | None = None,
-                    block_q: int = 128, block_k: int = 128) -> jax.Array:
-    """Blocked attention; q (B,H,S,D), kv (B,KVH,S,D)."""
-    if not _use_pallas():
-        return _ref.flash_attention_ref(q, k, v, causal=causal, scale=scale)
-    return flash_attention_pallas(q, k, v, causal=causal, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=_interpret())

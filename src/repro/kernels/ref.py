@@ -140,22 +140,3 @@ def fused_prox_svrg_diff_ref(u, dv, z, *, eta, lam1, lam2):
     """Oracle for the 3-operand diff variant (dv = g_u - g_w precombined)."""
     return prox_elastic_net(u - eta * (dv + z), eta, lam1, lam2)
 
-
-def flash_attention_ref(q, k, v, *, causal=True, scale=None):
-    """Oracle for kernels/flash_attention: exact softmax attention, fp32.
-
-    q: (B, H, Sq, D); k, v: (B, KVH, Sk, D) with GQA head grouping.
-    """
-    B, H, Sq, D = q.shape
-    KVH, Sk = k.shape[1], k.shape[2]
-    group = H // KVH
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    kf = jnp.repeat(k.astype(jnp.float32), group, axis=1)
-    vf = jnp.repeat(v.astype(jnp.float32), group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), kf) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
-        s = jnp.where(mask[None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vf).astype(q.dtype)

@@ -9,7 +9,7 @@ exists to preserve that property across *real processes*:
   * `MeshSpec` — declarative layout/mesh-shape separation (the
     tensor2tensor idiom): a mesh *shape* over named device axes plus a
     logical->mesh layout for the solver's two logical axes
-    (`workers` / `features`, see `repro.sharding.logical`).  Importing
+    (`workers` / `features`, `SOLVER_LOGICAL_AXES`).  Importing
     this module never touches jax device state; `spec.build()` does.
   * `init_distributed` — `jax.distributed.initialize` with the gloo
     CPU-collectives backend selected, idempotent, env-var defaulted, so
@@ -46,20 +46,18 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
-from repro.sharding.logical import SOLVER_LOGICAL_AXES, solver_rules
+
+# The solver's two logical axes: `workers` (the partition axis: shard
+# rows, labels, statics) and `features` (the d coordinates of w and z,
+# unsharded today).
+SOLVER_LOGICAL_AXES = ("workers", "features")
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: (data=16, model=16) = 256 chips of TPU v5e-class.
-    Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / elastic resizing)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def solver_rules(workers_axis: str = "workers",
+                 features_axis: Optional[str] = None
+                 ) -> Dict[Optional[str], Optional[str]]:
+    """Logical->mesh layout for the CALL solver arrays."""
+    return {None: None, "workers": workers_axis, "features": features_axis}
 
 
 # Hardware constants (TPU v5e-class, per chip) used by the roofline —

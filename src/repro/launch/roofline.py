@@ -1,10 +1,9 @@
-"""Roofline-term extraction from a compiled dry-run artifact.
+"""Trip-count-aware HLO cost model over `compiled.as_text()`.
 
 XLA's `compiled.cost_analysis()` counts while-loop bodies ONCE (scan
-bodies are not multiplied by trip count), which silently undercounts
-FLOPs/bytes for scan-over-layers models by ~L x n_microbatches.  We
-therefore implement a trip-count-aware HLO cost model over
-`compiled.as_text()`:
+bodies are not multiplied by trip count), which undercounts the FLOPs,
+bytes and collectives of a scanned solver round by its trip count.
+This module parses the compiled module instead:
 
   * module parsed into computations and instructions,
   * dot FLOPs = 2 * prod(out_shape) * prod(lhs contracting dims)
@@ -17,6 +16,10 @@ therefore implement a trip-count-aware HLO cost model over
   * collectives accumulated with ring-transfer factors and classified
     intra-pod vs cross-pod from replica_groups (incl. iota form
     [G,S]<=[dims]T(perm)).
+
+`benchmarks/bench_comm.py`, `tests/test_multihost.py` and
+`tests/test_roofline.py` use it to audit the CALL round's all-reduce
+bytes and the scanned round's FLOPs and bytes.
 
 All shapes in SPMD-partitioned HLO are per-device, so every number
 below is per-chip.
@@ -315,46 +318,3 @@ def roofline_terms(costs: Costs,
     dom = max(terms, key=terms.get)
     return {**terms, "bottleneck": dom, "t_total_max": terms[dom]}
 
-
-# ---------------------------------------------------------------------------
-# analytic useful-FLOPs model (6*N*D train / 2*N*D forward per token)
-# ---------------------------------------------------------------------------
-
-def active_param_count(cfg) -> float:
-    """Active (per-token) parameter count from the config."""
-    d, L, V = cfg.d_model, cfg.num_layers, cfg.vocab_size
-    Dh = cfg.resolved_head_dim
-    H, KVH = cfg.num_heads, cfg.num_kv_heads
-    attn = d * Dh * (H + 2 * KVH) + H * Dh * d
-    if cfg.family == "moe":
-        ff = (3 * d * cfg.moe.expert_ff * cfg.moe.top_k
-              + d * cfg.moe.num_experts)
-    else:
-        ff = 3 * d * cfg.d_ff
-    if cfg.family == "ssm":
-        s = cfg.ssm
-        d_in = s.expand * d
-        attn = 5 * d * d_in + d_in * d + d * 64 * 2  # r,k,v,g + lora + o
-        ff = d * d + 2 * d * cfg.d_ff                # channel mix
-    if cfg.family == "hybrid":
-        s = cfg.ssm
-        d_in = s.expand * d
-        mamba = 2 * d * d_in + d_in * d + 2 * d * s.state_dim + d * (
-            d_in // s.head_dim)
-        every = cfg.shared_attn_every or L
-        n_apps = L // every
-        shared = d * Dh * (H + 2 * KVH) + H * Dh * d + 3 * d * cfg.d_ff
-        return float(L * mamba + n_apps * shared + 2 * V * d)
-    total = L * (attn + ff) + 2 * V * d
-    if cfg.family == "audio":
-        total += cfg.encoder_layers * (attn + 3 * d * cfg.d_ff)
-        total += L * attn  # decoder cross-attention
-    return float(total)
-
-
-def model_flops(cfg, shape, backward: bool) -> float:
-    n_active = active_param_count(cfg)
-    tokens = shape.global_batch * (shape.seq_len
-                                   if shape.kind != "decode" else 1)
-    per_tok = 6.0 * n_active if backward else 2.0 * n_active
-    return per_tok * tokens

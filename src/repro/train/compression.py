@@ -8,7 +8,7 @@ round.  pSCOPE communicates the anchor gradient once per OUTER round
 multiplicatively on top — at ratio=0.01 the cross-pod bytes per round
 drop ~100x (the z all-reduce is the only cross-pod traffic).
 
-The dense mask-based form below is what lowers in the dry-run; on a
+The dense mask-based form below is what lowers under jit; on a
 real deployment the masked tensor is sent as (indices, values) pairs —
 bytes accounting in benchmarks uses 2 * ratio * size (values + indices).
 """

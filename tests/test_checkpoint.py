@@ -67,42 +67,3 @@ def test_elastic_reshard_roundtrip(tmp_path):
     out = reshard_tree(tree, mesh, specs)
     np.testing.assert_array_equal(np.asarray(out["params"]["w"]),
                                   np.arange(12.0).reshape(3, 4))
-
-
-def test_train_loop_failure_restart(tmp_path):
-    """Crash at step 7, restart, final state identical to uninterrupted."""
-    from repro.train.train_loop import run_training, LoopConfig
-
-    def make(dirname):
-        def init_state():
-            return {"w": jnp.zeros(4), "step": jnp.asarray(0, jnp.int32)}
-
-        def step_fn(state, batch, step):
-            w = state["w"] + batch["x"]
-            return {"w": w, "step": state["step"] + 1}, {"loss": w.sum()}
-
-        def batch_fn(step):
-            return {"x": jnp.full(4, float(step))}
-
-        cfg = LoopConfig(total_steps=12, checkpoint_every=3,
-                         checkpoint_dir=str(tmp_path / dirname))
-        return init_state, step_fn, batch_fn, cfg
-
-    # uninterrupted run
-    i1, s1, b1, c1 = make("a")
-    final_a = run_training(s1, i1, b1, c1)
-
-    # crashing run: fails once at step 7, then restarted
-    i2, s2, b2, c2 = make("b")
-    boom = {"armed": True}
-
-    def failure_hook(step):
-        if step == 7 and boom["armed"]:
-            boom["armed"] = False
-            raise RuntimeError("injected node failure")
-
-    with pytest.raises(RuntimeError):
-        run_training(s2, i2, b2, c2, failure_hook=failure_hook)
-    final_b = run_training(s2, i2, b2, c2, failure_hook=failure_hook)
-    np.testing.assert_array_equal(np.asarray(final_a["w"]),
-                                  np.asarray(final_b["w"]))

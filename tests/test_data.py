@@ -1,45 +1,8 @@
-"""Data pipeline determinism + block-sparse representation."""
+"""Synthetic datasets + block-sparse representation."""
 import numpy as np
-import jax
 
-from repro.data.pipeline import TokenDataset, ShardedBatchIterator
 from repro.data.synthetic import (make_dataset, make_block_sparse,
                                   pad_features, make_sparse_classification)
-
-
-def test_token_dataset_deterministic():
-    ds = TokenDataset(vocab_size=1000, seed=3)
-    a = ds.sample(5, 4, 16)
-    b = ds.sample(5, 4, 16)
-    np.testing.assert_array_equal(a, b)
-    c = ds.sample(6, 4, 16)
-    assert not np.array_equal(a, c)
-    assert a.max() < 1000 and a.min() >= 0
-
-
-def test_iterator_restart_resumes_exactly():
-    ds = TokenDataset(vocab_size=100, seed=0)
-    it = ShardedBatchIterator(ds, global_batch=8, seq=16)
-    batches = [next(it) for _ in range(5)]
-    state = it.state()
-    it2 = ShardedBatchIterator(ds, global_batch=8, seq=16)
-    it2.restore(state)
-    nxt_a = next(it)
-    nxt_b = next(it2)
-    np.testing.assert_array_equal(nxt_a[0], nxt_b[0])
-
-
-def test_iterator_host_sharding_partitions_batch():
-    ds = TokenDataset(vocab_size=100, seed=0)
-    full = ShardedBatchIterator(ds, global_batch=8, seq=4)
-    h0 = ShardedBatchIterator(ds, global_batch=8, seq=4, host_id=0,
-                              num_hosts=2)
-    h1 = ShardedBatchIterator(ds, global_batch=8, seq=4, host_id=1,
-                              num_hosts=2)
-    f = next(full)[0]
-    a = next(h0)[0]
-    b = next(h1)[0]
-    np.testing.assert_array_equal(np.concatenate([a, b]), f)
 
 
 def test_block_sparse_roundtrip():

@@ -66,57 +66,68 @@ def test_pscope_distributed_equals_simulation(driver):
     assert "OK" in out
 
 
-def test_pscope_dl_step_collective_structure():
-    """On a (pod,data,model) mesh the pSCOPE DL step's cross-pod traffic
-    is exactly the two phase all-reduces (z + averaging), while the
-    standard step all-reduces every microbatch."""
-    out = _run(8, """
-        import jax, jax.numpy as jnp, re, json
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.configs.base import ModelConfig
-        from repro.models import build_model
-        from repro.sharding import rules_for_config
-        from repro.optim.pscope_dl import (PScopeDLConfig,
-            make_pscope_train_step, make_standard_train_step,
-            init_train_state)
-        from repro.optim import optimizers as opt
+@pytest.mark.parametrize("layout", ["dense", "lazy", "encoded"])
+def test_call_round_collectives(layout):
+    """The compiled CALL round over a 4-device mesh holds exactly
+    COMM_ALLREDUCES_PER_ROUND all-reduces, each of d float32, and none
+    inside a loop body: the inner epoch is collective-free.  `lazy` is
+    the cells' layout (flat CSR rows with sharded statics); `encoded`
+    is the storage codec's body."""
+    out = _run(4, f"""
+        import re
+        import jax, jax.numpy as jnp
+        from repro.core import Regularizer, LOGISTIC, PScopeConfig
+        from repro.core import pscope
+        from repro.data.sparse import dense_to_csr, encode_csr
+        from repro.data.synthetic import make_sparse_classification
         from repro.launch import roofline as rf
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-        cfg = ModelConfig(name="t", family="dense", num_layers=2,
-                          d_model=64, num_heads=2, num_kv_heads=2,
-                          d_ff=128, vocab_size=256, head_dim=32)
-        rules = rules_for_config(cfg, "tp", True, tp_size=2)
-        model = build_model(cfg, rules)
-        params = model.abstract_params()
-        batch = {"tokens": jax.ShapeDtypeStruct((16, 64), jnp.int32),
-                 "labels": jax.ShapeDtypeStruct((16, 64), jnp.int32)}
-        sh = lambda s: NamedSharding(mesh, s)
-        pss = jax.tree_util.tree_map(sh, model.param_pspecs())
-        bsh = {k: sh(P(("pod", "data"))) for k in batch}
-        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        layout = {layout!r}
+        n, d = 256, 40
+        X, y, _ = make_sparse_classification(n, d, density=0.2, seed=0)
+        X, y = jnp.asarray(X), jnp.asarray(y)
+        if layout != "dense":
+            X = dense_to_csr(X)
+        if layout == "encoded":
+            X = encode_csr(X)
+        mesh = jax.make_mesh((4,), ("workers",))
+        reg = Regularizer(1e-3, 1e-3)
+        cfg = PScopeConfig(eta=0.5, inner_steps=16, inner_batch=2,
+                           outer_steps=1,
+                           inner_path="dense" if layout == "dense"
+                           else "lazy")
+        cfg, X, statics = pscope._prepare_distributed(
+            LOGISTIC, reg, X, y, cfg, mesh, "workers")
+        assert (statics is not None) == (layout == "lazy")
+        step = pscope.make_distributed_outer_step_core(
+            LOGISTIC, reg, cfg, mesh, "workers")
+        c = (jax.jit(step).lower(pscope.init_state(jnp.zeros(d)), X, y,
+                                 statics).compile())
+        model = rf.HloCostModel(c.as_text())
 
-        pcfg = PScopeDLConfig(inner_steps=2, num_microbatches=2,
-                              worker_axes=("pod",), unroll_loops=True)
-        step = make_pscope_train_step(model, mesh, pcfg, donate=False)
-        state = jax.eval_shape(lambda p: init_train_state(p, pcfg), params)
-        with mesh:
-            c = jax.jit(step.__wrapped__,
-                in_shardings=(pss, jax.tree_util.tree_map(
-                    lambda _: sh(P()), state), bsh, sh(P()))
-                ).lower(params, state, batch, key).compile()
-        costs = rf.analyze_hlo(c.as_text(), chips_per_pod=4)
-        # cross-pod all-reduce count == 2 param-tree rounds (z, avg) + loss
-        crossed = costs.coll_cross
-        assert crossed > 0
-        n_leaves = len(jax.tree_util.tree_leaves(params))
-        per_round = sum(
-            p.size * 4 for p in jax.tree_util.tree_leaves(params))
-        # cross-pod bytes should be ~ 2 rounds of the (fp32 z + bf16 u)
-        # param tree, far below M*n_mb rounds
-        print("cross", crossed, "bound", 4 * per_round)
-        assert crossed < 4 * per_round
-        print("OK")
+        # every computation a while loop runs, through any nesting
+        def callees(ins):
+            return [n for n in re.findall(r"%([\\w.\\-]+)", ins.line)
+                    if n in model.comps]
+
+        looped = set()
+        todo = [n for comp in model.comps.values() for i in comp
+                if i.opcode == "while" for n in callees(i)]
+        while todo:
+            name = todo.pop()
+            if name not in looped:
+                looped.add(name)
+                todo += [n for i in model.comps[name] for n in callees(i)]
+
+        ars = [(comp, ins) for comp, body in model.comps.items()
+               for ins in body if ins.opcode.startswith("all-reduce")
+               and not ins.opcode.endswith("-done")]
+        assert len(ars) == pscope.COMM_ALLREDUCES_PER_ROUND, ars
+        for comp, ins in ars:
+            assert (ins.dtype, ins.dims) == ("f32", (d,)), ins.line
+            assert comp not in looped, (comp, ins.line)
+        assert looped, "no while loop: the inner epoch was not scanned"
+        print("OK", len(ars))
     """)
     assert "OK" in out
 

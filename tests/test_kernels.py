@@ -107,44 +107,3 @@ def test_fused_prox_svrg_hyperparams(eta, lam1, lam2):
                                    lam2=lam2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("B,H,KVH,S,D", [
-    (1, 4, 4, 128, 64),     # MHA
-    (2, 4, 2, 256, 64),     # GQA
-    (1, 8, 1, 128, 32),     # MQA
-])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_grid(B, H, KVH, S, D, causal):
-    rng = np.random.RandomState(B + H)
-    q = jnp.asarray(rng.randn(B, H, S, D).astype(np.float32)) * 0.3
-    k = jnp.asarray(rng.randn(B, KVH, S, D).astype(np.float32)) * 0.3
-    v = jnp.asarray(rng.randn(B, KVH, S, D).astype(np.float32))
-    got = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-5)
-
-
-def test_flash_attention_bf16():
-    rng = np.random.RandomState(3)
-    q = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16) * 0.3
-    k = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16) * 0.3
-    v = jnp.asarray(rng.randn(1, 2, 128, 64), jnp.bfloat16)
-    got = ops.flash_attention(q, k, v, causal=True)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=0.1, atol=0.05)
-
-
-def test_flash_attention_uneven_blocks():
-    """seq not a multiple of the default block -> block clamping path."""
-    rng = np.random.RandomState(5)
-    q = jnp.asarray(rng.randn(1, 2, 64, 32).astype(np.float32))
-    k = jnp.asarray(rng.randn(1, 2, 64, 32).astype(np.float32))
-    v = jnp.asarray(rng.randn(1, 2, 64, 32).astype(np.float32))
-    got = ops.flash_attention(q, k, v, causal=True)   # blocks clamp to 64
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-5)

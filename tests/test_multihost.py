@@ -24,8 +24,7 @@ from distributed_harness import (ROOT, multihost, run_forced_devices,
 # Keep jax single-device in THIS process: everything device-shaped runs
 # in children.  Importing repro modules here is fine (import is
 # device-state free by design).
-from repro.launch.mesh import MeshSpec, comm_bytes_per_round
-from repro.sharding.logical import solver_rules
+from repro.launch.mesh import MeshSpec, comm_bytes_per_round, solver_rules
 from repro.core.pscope import COMM_ALLREDUCES_PER_ROUND
 
 FIXTURE_D = 32

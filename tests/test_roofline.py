@@ -76,12 +76,3 @@ ENTRY %main (p: f32[4]) -> f32[4] {
     costs = rf.analyze_hlo(hlo, chips_per_pod=2)
     assert costs.coll_cross > 0 and costs.coll_intra == 0
 
-
-def test_model_flops_moe_active_only():
-    from repro import configs
-    from repro.configs.base import SHAPES
-    cfg = configs.get("qwen3-moe-30b-a3b")
-    n_act = rf.active_param_count(cfg)
-    assert 2e9 < n_act < 5e9        # ~3B active of 30B total
-    f = rf.model_flops(cfg, SHAPES["train_4k"], backward=True)
-    assert f == pytest.approx(6 * n_act * 256 * 4096)

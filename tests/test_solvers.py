@@ -115,7 +115,7 @@ def test_trace_derived_metrics():
 
 
 def test_trace_records_pytrees():
-    """The DL train loop streams whole param trees into the same Trace."""
+    """A pytree iterate records one NNZ over all of its leaves."""
     tr = Trace(solver="train", objective="lm", partition="pod", p=2, d=0)
     params = {"wq": jnp.asarray([1.0, 0.0]), "mlp": {"w": jnp.zeros((2, 2))}}
     tr.record(params, 3.5, 2.0)
