@@ -25,18 +25,28 @@ duplicate representatives arrive in SMEM, one scalar per slot, beside
 the step's fold bound.
 
 Mosaic lowers no vector gather or scatter, so the step moves the
-touched coordinates slot by slot: a scalar column index from SMEM
-selects an (8, 128) tile by its leading index, and a lane mask picks
-or replaces the one coordinate in it.  Duplicate columns of a step are
+touched coordinates slot by slot, each by placing it and not by
+picking it out.  A scalar column index c from SMEM selects an (8, 128)
+tile by its leading index (c >> 10).  The gather masks the tile to the
+one position c & 1023, sums its 8 sublanes into one row that holds the
+value at lane c & 127, and rotates that row (`pltpu.roll`) so the
+value lands on its slot's lane.  The scatter rotates the slot's lane
+of the step's new values to lane c & 127, broadcasts it over the
+sublanes and writes it with a masked store of that one position: no
+tile load and no branch.  Duplicate columns of a step are
 folded into their representative slot in slot order (the reference's
-scatter-add order), and only representatives are written back.
+scatter-add order), and only representatives are written back: their
+columns are distinct, so the single-position stores commute.
 
 The slot loops run only over slots that can change the iterate: the
 gather and the scatter over the b * k real slots (a padded slot's
 gather reads 0 and its write-back stores 0, which the zero-initialised
 gather block and the untouched spare coordinate already hold), and the
 fold up to the step's bound, one past its last duplicate slot
-(`core.plan.fold_bounds`; every later slot represents itself).
+(`core.plan.fold_bounds`; every later slot represents itself).  The
+gather and the scatter run their slots in groups of `_GROUP` written
+out in one loop body, the remainder after the last whole group
+unrolled, so the independent slots of a group can overlap.
 """
 from __future__ import annotations
 
@@ -53,6 +63,8 @@ from repro.kernels.lazy_prox import _catch_up_block
 _LANES = 128
 _TILE_BITS = 10                     # an (8, 128) tile holds 1024 coordinates
 _TILE_MASK = (1 << _TILE_BITS) - 1
+# slots a gather / scatter loop trip: the fastest of 1 to 64 on a v5e
+_GROUP = 64
 
 
 def _pick(x, mask):
@@ -75,13 +87,23 @@ def _epoch_kernel(cf_ref, rep_ref, nf_ref, u0_ref, z_ref, qf_ref, q_ref,
             return carry
         jax.lax.fori_loop(0, n_tiles, step, 0)
 
-    def over_real_slots(body, carry):
-        # slot i of sample j sits at j * kp + i; slots i >= n_cols are
-        # padding
-        def sample(j, carry):
-            return jax.lax.fori_loop(0, n_cols,
-                                     lambda i, c: body(j * kp + i, c), carry)
-        return jax.lax.fori_loop(0, b, sample, carry)
+    def over_slots(n, body, carry):
+        # body(i, carry) for i in [0, n), n static: a loop over whole
+        # groups of _GROUP slots, then the remainder written out
+        def group(t, carry):
+            for u in range(_GROUP):
+                carry = body(t * _GROUP + u, carry)
+            return carry
+        carry = jax.lax.fori_loop(0, n // _GROUP, group, carry)
+        for i in range(n - n % _GROUP, n):
+            carry = body(i, carry)
+        return carry
+
+    def lane_blocks():
+        # (first slot, real slots i < n_cols) of each 128-lane block of a
+        # sample's kp slots
+        return [(lo, max(0, min(n_cols - lo, _LANES)))
+                for lo in range(0, kp, _LANES)]
 
     @pl.when(i == 0)
     def _init():
@@ -91,16 +113,28 @@ def _epoch_kernel(cf_ref, rep_ref, nf_ref, u0_ref, z_ref, qf_ref, q_ref,
 
     slot = (jax.lax.broadcasted_iota(jnp.int32, (b, kp), 0) * kp
             + jax.lax.broadcasted_iota(jnp.int32, (b, kp), 1))
+    sample = jax.lax.broadcasted_iota(jnp.int32, (b, kp), 0)
     pos = (jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 0) * _LANES
            + jax.lax.broadcasted_iota(jnp.int32, (8, _LANES), 1))
 
-    # 1. gather the touched coordinates, one slot at a time
-    def gather(s, g):
-        c = cf_ref[0, s]
-        val = _pick(o_ref[c >> _TILE_BITS], pos == (c & _TILE_MASK))
-        return jnp.where(slot == s, val, g)
+    # 1. gather the touched coordinates: slot i of sample j reads column
+    #    c into lane i of row j
+    def gather_sample(j, g):
+        rows = []
+        for lo, n in lane_blocks():
+            def gather(i, acc, base=j * kp + lo):
+                c = cf_ref[0, base + i]
+                x = jnp.where(pos == (c & _TILE_MASK),
+                              o_ref[c >> _TILE_BITS], 0.0)
+                at_c = jnp.sum(x, axis=0, keepdims=True)   # lane c & 127
+                return acc + pltpu.roll(at_c, (i - c) & (_LANES - 1), 1)
+            rows.append(over_slots(n, gather,
+                                   jnp.zeros((1, _LANES), jnp.float32)))
+        row = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
+        return jnp.where(sample == j, row, g)
 
-    u_g = over_real_slots(gather, jnp.zeros((b, kp), jnp.float32))
+    u_g = jax.lax.fori_loop(0, b, gather_sample,
+                            jnp.zeros((b, kp), jnp.float32))
 
     # 2. Lemma-11 catch-up, then the support-restricted VR gradient
     #    entries (anchor half precomputed)
@@ -129,16 +163,26 @@ def _epoch_kernel(cf_ref, rep_ref, nf_ref, u0_ref, z_ref, qf_ref, q_ref,
     # 3. eta-step + elastic-net prox, written back by representatives
     new = prox_elastic_net(u_t - eta * (zgm + ge), eta, lam1, lam2)
 
-    def scatter(s, carry):
-        @pl.when(rep_ref[0, s] == s)
-        def _write():
-            c = cf_ref[0, s]
-            t = c >> _TILE_BITS
-            o_ref[t] = jnp.where(pos == (c & _TILE_MASK),
-                                 _pick(new, slot == s), o_ref[t])
+    def scatter_sample(j, carry):
+        row = jnp.sum(jnp.where(sample == j, new, 0.0), axis=0,
+                      keepdims=True)                              # (1, kp)
+        for lo, n in lane_blocks():
+            def scatter(i, carry, base=j * kp + lo,
+                        block=row[:, lo:lo + _LANES]):
+                s = base + i
+                c = cf_ref[0, s]
+                at_c = pltpu.roll(block, (c - i) & (_LANES - 1), 1)
+                # only a representative writes (a non-representative's
+                # mask is empty: no position is -1)
+                p = jnp.where(rep_ref[0, s] == s, c & _TILE_MASK, -1)
+                pltpu.store(o_ref.at[c >> _TILE_BITS],
+                            jnp.broadcast_to(at_c, (8, _LANES)),
+                            mask=pos == p)
+                return carry
+            over_slots(n, scatter, 0)
         return carry
 
-    over_real_slots(scatter, 0)
+    jax.lax.fori_loop(0, b, scatter_sample, 0)
 
     @pl.when(i == n_steps - 1)
     def _final_catch_up():

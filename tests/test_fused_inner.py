@@ -30,7 +30,7 @@ from repro.core.svrg import logistic_h_prime
 from repro.data import dense_to_csr, csr_partition
 from repro.data.sparse import make_csr_classification
 from repro.data.synthetic import make_sparse_classification
-from repro.kernels import ops
+from repro.kernels import ops, sparse_inner
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -342,6 +342,60 @@ def test_pallas_epoch_kernel_full_lane_rows(monkeypatch):
     assert plan_mod.padded_slot_width(128) == 128
     idx = jnp.asarray(rng.randint(0, 6, size=(6, 1)), jnp.int32)
     _run_kernel_vs_jnp(monkeypatch, vals, cols, idx, seed=12)
+
+
+@pytest.mark.parametrize("k,b,d", [(sparse_inner._GROUP + 6, 1, 300),
+                                   (5, 2, 200), (41, 3, 600), (74, 2, 1500),
+                                   (150, 2, 1500)],
+                         ids=["remainder", "fewer_than_group",
+                              "one_tile_b3", "two_tiles_b2",
+                              "two_lane_blocks"])
+def test_pallas_epoch_kernel_slot_groups(monkeypatch, k, b, d):
+    """The gather and scatter place each coordinate by a lane rotation
+    and a masked store, in loops of `_GROUP` slots and a remainder
+    written out: k not a multiple of the group (or below it), rows of
+    samples j >= 1 rotated into place, one or two tiles holding every
+    representative of a step, and k > 128 (a second lane block).
+
+    Random rows are compared with the jnp scan by tolerance only: the
+    kernel's arithmetic runs in another order than `kernels/ref.py`'s
+    (the dot product over the padded lanes, the catch-up through a
+    polynomial expm1 instead of the reference's tables), so their last
+    bits differ.  An epoch whose arithmetic is exact (no values, no
+    regularizer, a dyadic iterate and anchor gradient) leaves every
+    coordinate at u0 - M * eta * z whichever steps touched it: there the
+    kernel and the reference agree bit for bit, which pins the movement
+    itself.
+    """
+    assert k % sparse_inner._GROUP != 0
+    rng = np.random.RandomState(40 + k)
+    n_k, M = 10, 16
+    cols = jnp.asarray(np.stack([rng.choice(d, k, replace=False)
+                                 for _ in range(n_k)]).astype(np.int32))
+    vals = jnp.asarray(rng.randn(n_k, k).astype(np.float32))
+    idx = jnp.asarray(rng.randint(0, n_k, size=(M, b)), jnp.int32)
+    y = jnp.asarray(np.sign(rng.randn(n_k)).astype(np.float32))
+    w = jnp.asarray(rng.randn(d).astype(np.float32) * 0.1)
+    z = jnp.asarray(rng.randn(d).astype(np.float32) * 0.02)
+    w_int = jnp.asarray(rng.randint(-64, 64, d).astype(np.float32))
+    z_dyadic = jnp.asarray(rng.randint(-8, 8, d).astype(np.float32) / 8)
+
+    def run():
+        rand = _lazy_inner_loop(logistic_h_prime, Regularizer(1e-2, 1e-3),
+                                0.3, w, w, z, vals, cols, y, idx)
+        exact = _lazy_inner_loop(logistic_h_prime, Regularizer(0.0, 0.0),
+                                 0.5, w_int, w_int, z_dyadic,
+                                 jnp.zeros_like(vals), cols, y, idx)
+        return np.asarray(rand), np.asarray(exact)
+
+    ref_rand, ref_exact = run()
+    monkeypatch.setenv("USE_PALLAS", "1")
+    monkeypatch.setenv("REPRO_SPARSE_INNER_KERNEL", "1")
+    ker_rand, ker_exact = run()
+    np.testing.assert_allclose(ker_rand, ref_rand, atol=5e-6, rtol=1e-4)
+    want = np.asarray(w_int) - M * 0.5 * np.asarray(z_dyadic)
+    np.testing.assert_array_equal(ref_exact, want)
+    np.testing.assert_array_equal(ker_exact, ref_exact)
 
 
 def _some_duplicate_rows(rng, k=9):

@@ -73,15 +73,21 @@ def test_elementwise_kernel_compiles_at_rcv1_width(spec, kernel):
                        f32, f32, f32)
 
 
-@pytest.mark.parametrize("vals", ["f32", "bf16_bits"])
-def test_fused_lazy_epoch_compiles_at_rcv1_width(spec, vals):
-    tile_f32 = spec((TILES, 8, 128), jnp.float32)
-    slots = spec((M, 1, B * KP), jnp.int32)
-    step_f32 = spec((M, B, KP), jnp.float32)
-    vb = spec((M, B, KP), jnp.float32 if vals == "f32" else jnp.uint16)
-    per_sample = spec((M, B, 1), jnp.float32)
+@pytest.mark.parametrize("vals,workers", [("f32", None),
+                                          ("bf16_bits", None), ("f32", 8)],
+                         ids=["f32", "bf16_bits", "f32-vmap8"])
+def test_fused_lazy_epoch_compiles_at_rcv1_width(spec, vals, workers):
+    """One worker's epoch, and 8 workers' under `jax.vmap` as
+    `rcv1.uniform` runs them (the batch becomes a grid axis)."""
+    lead = () if workers is None else (workers,)
+    at = lambda shape, dtype: spec(lead + shape, dtype)  # noqa: E731
+    tile_f32 = at((TILES, 8, 128), jnp.float32)
+    slots = at((M, 1, B * KP), jnp.int32)
+    step_f32 = at((M, B, KP), jnp.float32)
+    vb = at((M, B, KP), jnp.float32 if vals == "f32" else jnp.uint16)
+    per_sample = at((M, B, 1), jnp.float32)
 
-    fold_n = spec((M, 1, 1), jnp.int32)
+    fold_n = at((M, 1, 1), jnp.int32)
 
     def epoch(*a):
         return fused_lazy_epoch_pallas(
@@ -89,10 +95,10 @@ def test_fused_lazy_epoch_compiles_at_rcv1_width(spec, vals):
             eta_eff=2.0 / (1.0 + 2e-4), lam1=1e-4, lam2=1e-4, n_cols=K,
             interpret=False)
 
-    _assert_kernel(epoch, tile_f32, tile_f32,
-                   spec((TILES, 8, 128), jnp.int32), slots,
-                   spec((M, B, KP), jnp.int32), slots, fold_n, vb,
-                   per_sample, step_f32, per_sample)
+    _assert_kernel(epoch if workers is None else jax.vmap(epoch),
+                   tile_f32, tile_f32, at((TILES, 8, 128), jnp.int32), slots,
+                   at((M, B, KP), jnp.int32), slots, fold_n, vb, per_sample,
+                   step_f32, per_sample)
 
 
 @pytest.mark.parametrize("workers,rows", [(8, 2_530), (None, 5_060)],
