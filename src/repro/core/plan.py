@@ -28,8 +28,12 @@ Two plan builders, selected by shard shape:
   the same column, so a shifted compare finds every ``last``, a
   running max copies it to the group's duplicates, and a sort by
   position puts the plan back in step order.  No binary search.
+  Where d * (M + 1) or (N + d) * S no longer fits one int32 (news20's
+  1,355,191 features at M = 2,499), the wide variant sorts on the
+  column alone and hands each head's plan to its duplicates by a
+  gather (scope ``pscope.plan_wide``).
 
-Both produce identical plans (tests/test_fused_inner.py enforces it
+All produce identical plans (tests/test_fused_inner.py enforces it
 against a literal Python replay).
 
 `ShardStatics` holds the data-only precomputes — duplicate-column
@@ -53,6 +57,10 @@ Array = jax.Array
 # Above this many elements the member[r, s, r'] table is not built and
 # the sort-based plan is used instead (the table is O(n_k^2 * k)).
 MEMBER_TABLE_LIMIT = 48_000_000
+
+# The named scope of the wide sort plan, inside the round's `pscope.plan`
+# (docs/observability.md).
+SCOPE_PLAN_WIDE = "pscope.plan_wide"
 
 
 # ---------------------------------------------------------------------------
@@ -179,12 +187,23 @@ def build_epoch_plan(cols_k: Array, idx: Array, d: int,
 
     ``idx`` is the (M, b) sampled row sequence.  Dispatches to the
     row-membership builder when ``statics`` carries a member table and
-    b == 1, else to the general sort-based builder.
+    b == 1, else to the sort-based builder: the packed one where its
+    keys fit one int32, the wide one past that.
     """
     M, b = idx.shape
     if b == 1 and statics is not None and statics.member is not None:
         return _plan_from_membership(cols_k, idx, d, statics)
-    return _plan_from_sort(cols_k, idx, d)
+    if packed_plan_fits(d, M, b * cols_k.shape[-1]):
+        return _plan_from_sort(cols_k, idx, d)
+    with jax.named_scope(SCOPE_PLAN_WIDE):
+        return _plan_from_sort_wide(cols_k, idx, d)
+
+
+def packed_plan_fits(d: int, M: int, S: int) -> bool:
+    """Whether `_plan_from_sort`'s packed values fit int32: the key and
+    the carried column and ``last`` (d * (M + 1)), and the carried
+    sorted index and slot ((M * S + d) * S)."""
+    return max(d * (M + 1), (M * S + d) * S) < (1 << 31)
 
 
 def _last_sampled(idx_flat: Array, n_k: int) -> tuple[Array, Array]:
@@ -231,18 +250,20 @@ def _plan_from_sort(cols_k: Array, idx: Array, d: int) -> EpochPlan:
     carries each head's ``last`` and slot to the group's duplicates,
     and a second sort, keyed on the position, puts every entry back in
     place (on a TPU it is faster than the scatter by position).  The
-    packed values must fit int32: the key and the carried column and
-    ``last`` (d * (M + 1)), and the carried sorted index and slot
-    ((N + d) * S).  At the paper's scales (d <= 2^18, M <= 2^12,
-    N * S <= 2^28) they do; a ValueError guards the boundary.
+    packed values must fit int32 (`packed_plan_fits`): the key and the
+    carried column and ``last`` (d * (M + 1)), and the carried sorted
+    index and slot ((N + d) * S).  rcv1 (d = 47,236, M <= 5,060) fits;
+    news20 (d = 1,355,191, M = 2,499: d * (M + 1) = 3.4e9) does not,
+    and `build_epoch_plan` takes `_plan_from_sort_wide` there.
     """
     M, b = idx.shape
     k = cols_k.shape[-1]
     S = b * k
     N = M * S
-    if max(d * (M + 1), (N + d) * S) >= (1 << 31):
+    if not packed_plan_fits(d, M, S):
         raise ValueError(
-            f"packed plan values overflow int32 for d={d}, M={M}, S={S}")
+            f"packed plan values overflow int32 for d={d}, M={M}, S={S}; "
+            f"past this bound build_epoch_plan takes _plan_from_sort_wide")
     cflat = jnp.take(cols_k, idx, axis=0).reshape(M, S)
     flat = jnp.arange(N + d, dtype=jnp.int32)   # step * S + slot, then N + j
     col = jnp.concatenate([cflat.reshape(-1), jnp.arange(d, dtype=jnp.int32)])
@@ -261,6 +282,43 @@ def _plan_from_sort(cols_k: Array, idx: Array, d: int) -> EpochPlan:
                                     0)) - scol * (M + 1)
     rep = jax.lax.cummax(jnp.where(head, flat * S + spos % S, 0)) % S
     _, packed = jax.lax.sort((spos, (sstep - last) * S + rep), num_keys=1)
+    return EpochPlan(cflat=cflat, q=(packed[:N] // S).reshape(M, S),
+                     rep=(packed[:N] % S).reshape(M, S), qf=packed[N:] // S)
+
+
+def _plan_from_sort_wide(cols_k: Array, idx: Array, d: int) -> EpochPlan:
+    """`_plan_from_sort` for a d and M whose packed key overflows int32.
+
+    The entries are sorted on the column alone.  The sort is stable and
+    the flat position is step * S + slot for a touch and N + j for
+    column j's probe, so each column's entries keep step order, then
+    slot order, with the probe last: the order the packed key gives.  A
+    group head is an entry whose (column, step) differs from its
+    neighbour's before it, and that neighbour gives ``last`` as in the
+    packed path.  Each head's staleness and slot are packed into one
+    value, and a running max of the heads' sorted index, with one
+    gather, hands them to the group's duplicates.  Only N + d and
+    (M + 1) * S have to fit int32.
+    """
+    M, b = idx.shape
+    k = cols_k.shape[-1]
+    S = b * k
+    N = M * S
+    if max(N + d, (M + 1) * S) >= (1 << 31):
+        raise ValueError(
+            f"plan positions overflow int32 for d={d}, M={M}, S={S}")
+    cflat = jnp.take(cols_k, idx, axis=0).reshape(M, S)
+    flat = jnp.arange(N + d, dtype=jnp.int32)   # step * S + slot, then N + j
+    col = jnp.concatenate([cflat.reshape(-1), jnp.arange(d, dtype=jnp.int32)])
+    scol, spos = jax.lax.sort((col, flat), num_keys=1, is_stable=True)
+    sstep = jnp.where(spos < N, spos // S, M)
+    pcol = jnp.concatenate([jnp.full((1,), -1, jnp.int32), scol[:-1]])
+    pstep = jnp.concatenate([jnp.full((1,), -1, jnp.int32), sstep[:-1]])
+    head = (scol != pcol) | (sstep != pstep)
+    last = jnp.where(pcol == scol, pstep + 1, 0)        # right at heads
+    at_head = (sstep - last) * S + spos % S
+    group = jax.lax.cummax(jnp.where(head, flat, 0))    # each one's head
+    _, packed = jax.lax.sort((spos, jnp.take(at_head, group)), num_keys=1)
     return EpochPlan(cflat=cflat, q=(packed[:N] // S).reshape(M, S),
                      rep=(packed[:N] % S).reshape(M, S), qf=packed[N:] // S)
 

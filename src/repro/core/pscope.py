@@ -99,6 +99,7 @@ COUNTER_NAMES = ("catch_up", "prox_skip", "dup_fold")
 # kernel keeps its own name and gets no scope.
 SCOPE_ANCHOR_GRAD = "pscope.anchor_grad"   # phase 1 and its all-reduce
 SCOPE_PLAN = "pscope.plan"                 # sampling, epoch plan, statics
+# (inside SCOPE_PLAN, `plan.SCOPE_PLAN_WIDE` names the plan past int32 keys)
 SCOPE_GATHER = "pscope.gather"             # the steps' operand gathers
 SCOPE_AVERAGE = "pscope.average"           # phase 3 and its all-reduce
 SCOPE_OBJECTIVE = "pscope.objective"       # recorded P(w) and NNZ

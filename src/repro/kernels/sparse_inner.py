@@ -38,6 +38,13 @@ folded into their representative slot in slot order (the reference's
 scatter-add order), and only representatives are written back: their
 columns are distinct, so the single-position stores commute.
 
+VMEM.  The pipeline double-buffers every block, and the four
+iterate-sized ones (u0, z, qf and the output) take 32 B a coordinate:
+1.4 MiB at rcv1's 47,236 features, under Mosaic's default scoped limit
+of 16 MiB on a v5e, and 41.4 MiB at news20's 1,355,191.  Past the
+default the kernel asks for the VMEM its blocks need, up to the v5e's
+128 MiB, which holds d up to `MAX_FEATURES` (about 4.06 M).
+
 The slot loops run only over slots that can change the iterate: the
 gather and the scatter over the b * k real slots (a padded slot's
 gather reads 0 and its write-back stores 0, which the zero-initialised
@@ -65,6 +72,30 @@ _TILE_BITS = 10                     # an (8, 128) tile holds 1024 coordinates
 _TILE_MASK = (1 << _TILE_BITS) - 1
 # slots a gather / scatter loop trip: the fastest of 1 to 64 on a v5e
 _GROUP = 64
+# Mosaic's default scoped-VMEM limit on a v5e, and the chip's VMEM
+_VMEM_DEFAULT = 16 << 20
+_VMEM_CAP = 128 << 20
+# room for the step's own blocks and Mosaic's internal scratch
+_VMEM_SLACK = 4 << 20
+# bytes of VMEM an (8, 128) tile of the iterate takes: u0, z, qf and the
+# output, each double-buffered
+_VMEM_PER_TILE = 4 * 2 * 8 * 128 * 4
+# the largest d whose tiles (with the spare tail slot) fit the cap
+MAX_FEATURES = (_VMEM_CAP - _VMEM_SLACK) // _VMEM_PER_TILE * 1024 - 1
+
+
+def _vmem_limit(n_tiles: int):
+    """The scoped-VMEM limit the kernel asks for: None (Mosaic's
+    default) where its blocks fit that, else what they need."""
+    need = n_tiles * _VMEM_PER_TILE + _VMEM_SLACK
+    if need <= _VMEM_DEFAULT:
+        return None
+    if need > _VMEM_CAP:
+        raise ValueError(
+            f"the epoch kernel's iterate of {n_tiles} tiles needs {need} B "
+            f"of VMEM, more than a v5e's {_VMEM_CAP}: d above "
+            f"{MAX_FEATURES} does not fit")
+    return need
 
 
 def _pick(x, mask):
@@ -229,6 +260,7 @@ def fused_lazy_epoch_pallas(u0_t: jax.Array, z_t: jax.Array, qf_t: jax.Array,
     kernel = functools.partial(_epoch_kernel, h_prime=h_prime, eta=eta,
                                eta_eff=eta_eff, lam1=lam1, lam2=lam2,
                                n_cols=n_cols, n_steps=M)
+    limit = _vmem_limit(T)
     return pl.pallas_call(
         kernel,
         grid=(M,),
@@ -237,5 +269,7 @@ def fused_lazy_epoch_pallas(u0_t: jax.Array, z_t: jax.Array, qf_t: jax.Array,
         out_specs=full,
         out_shape=jax.ShapeDtypeStruct(u0_t.shape, u0_t.dtype),
         interpret=interpret,
+        compiler_params=(None if limit is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=limit)),
         name="fused_lazy_epoch",
     )(cflat, rep, fold_n, u0_t, z_t, qf_t, q, vb, yb, zg, sw)

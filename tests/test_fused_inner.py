@@ -98,9 +98,9 @@ PLAN_CASES = ["random", "every_step", "one_col_row", "untouched", "m1"]
     pytest.param(builder, b, case,
                  id=f"{builder}-{b}" + ("" if case == "random"
                                         else f"-{case}"))
-    for builder in ("membership", "sort") for b in (1, 3)
+    for builder in ("membership", "sort", "wide") for b in (1, 3)
     for case in PLAN_CASES
-    if case == "random" or builder == "sort" or b == 1])
+    if case == "random" or builder != "membership" or b == 1])
 def test_epoch_plan_matches_replay(builder, b, case):
     rng = np.random.RandomState(0)
     vals, cols, idx, d = _plan_case(case, b, rng)
@@ -110,6 +110,8 @@ def test_epoch_plan_matches_replay(builder, b, case):
         statics = plan_mod.shard_statics(vals, cols, with_member=True)
         assert statics.member is not None
         eplan = plan_mod._plan_from_membership(cols, idx, d, statics)
+    elif builder == "wide":     # forced below the packed key's bound
+        eplan = plan_mod._plan_from_sort_wide(cols, idx, d)
     else:
         eplan = plan_mod._plan_from_sort(cols, idx, d)
     cf, q, rep, qf = _brute_plan(cols, idx, d)
